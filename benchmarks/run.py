@@ -23,6 +23,7 @@ evidence dump, and bit-exact replay all leave a record in CI.
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import sys
@@ -51,6 +52,9 @@ def main(argv: list[str] | None = None) -> None:
         bench_watch,
     )
     from benchmarks.report import paper_report
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
@@ -141,16 +145,17 @@ def main(argv: list[str] | None = None) -> None:
     ]:
         results[name] = _run(name, fn)
 
-    # roofline (requires dry-run artifacts)
-    try:
+    # roofline: runs only over dry-run artifacts; without them it is skipped
+    dryrun_dir = os.path.join("results", "dryrun")
+    if glob.glob(os.path.join(dryrun_dir, "*.json")):
         from benchmarks import roofline
-        rows = roofline.build_table()
-        if rows:
-            n_ok = sum(1 for r in rows if r.get("dominant") != "SKIPPED")
-            print(f"roofline_table,0,{json.dumps({'cells': n_ok})}")
-            results["roofline"] = rows
-    except Exception as e:  # dry-run not yet produced
-        print(f"roofline_table,0,{json.dumps({'error': str(e)})}")
+        rows = roofline.build_table(dryrun_dir)
+        n_ok = sum(1 for r in rows if r.get("dominant") != "SKIPPED")
+        print(f"roofline_table,0,{json.dumps({'cells': n_ok})}")
+        results["roofline"] = rows
+    else:
+        skipped = {"skipped": f"no dry-run artifacts in {dryrun_dir}"}
+        print(f"roofline_table,0,{json.dumps(skipped)}")
 
     print("\n=== detail ===")
     for name, payload in results.items():

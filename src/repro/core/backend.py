@@ -57,7 +57,9 @@ The engine's hot path is selected by two ``NetStatic`` fields:
       runs under the Pallas interpreter so CPU tests exercise it.
 
 Bit-parity: both backends consume the *same* assembled f32 bucket images
-and express the same f32 arithmetic; the pallas matmul is issued with a
+and express the same f32 arithmetic (every propagation contraction runs at
+``Precision.HIGHEST`` — a TPU's default f32 matmul would round the weights
+to bf16); the pallas matmul is issued with a
 single k-block (≤ ``_MAX_KBLOCK``) so its accumulation order matches
 ``jnp.dot`` at bucket sizes up to a few hundred — on CPU the two backends
 produce bit-identical spike rasters, asserted by ``tests/test_backends.py``
@@ -146,7 +148,8 @@ def _matmul(static, pre_row: jax.Array, w: jax.Array) -> jax.Array:
             interpret=static.pallas_interpret,
         )
         return out[0]
-    return jnp.dot(pre_row, w.astype(jnp.float32))
+    return jnp.dot(pre_row, w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _gather(static, pre_row: jax.Array, idx: jax.Array, w: jax.Array) -> jax.Array:
@@ -493,6 +496,7 @@ def propagate_fused(static, params, state, spikes, ring, t, payload):
             out = jax.lax.dot_general(
                 x[:, None, :], payload.class_w[ci],
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=f32)  # [B, 1, Q]
             for bpos, bi in enumerate(bids):
                 drives[bi] = out[bpos, 0]

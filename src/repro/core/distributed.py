@@ -28,12 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.5 exports shard_map at top level (check_vma kwarg)
-    from jax import shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-except ImportError:  # jax 0.4.x keeps it in experimental (check_rep kwarg)
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
 
 from repro.core import neurons as nrn
 from repro.core.network import CompiledNetwork
@@ -180,11 +174,11 @@ def make_step(mesh: Mesh, axis: str, ring_len: int, dt: float):
     )
     pspec_state = ShardedState(t=P(), key=P(), v=P(axis), u=P(axis), ring=P(None, axis))
 
-    return shard_map(
+    return jax.shard_map(
         _step, mesh=mesh,
         in_specs=(pspec_params, pspec_state),
         out_specs=(pspec_state, P(axis)),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
 
 

@@ -48,6 +48,7 @@ from repro.core.synapses import (
     dense_to_csr,
     init_stp_state,
 )
+from repro.kernels import ops as kops
 from repro.memory import MemoryLedger
 from repro.obs import watch as wspec
 from repro.precision import PrecisionPolicy, get_policy
@@ -121,8 +122,10 @@ class FusedPlan:
     sparse_ids: tuple[int, ...]  # bucket indices executed as CSR gathers
     # True when the whole tick lowers to the single Pallas program
     # (IZH4+generators only, CUBA, euler, no plasticity/STP, contiguous
-    # bucket spans).
+    # bucket spans); ``kernel_reason`` names the first rule a refused net
+    # breaks ("" when eligible).
     kernel_ok: bool
+    kernel_reason: str = ""
     tile_q: int = 128  # weight-tile columns streamed per grid step
     tile_r: int = 128  # CSR rows streamed per grid step
 
@@ -150,11 +153,18 @@ def _plan_fused(
             sparse_ids.append(bi)
         else:
             classes.setdefault((b.p, b.q), []).append(bi)
-    spans_ok = all(b.pre_start >= 0 and b.post_start >= 0 for b in buckets)
-    kernel_ok = (
-        channels == 1 and izh4_only and method == "euler" and spans_ok
-        and not any(s.plastic or s.stp is not None for s in specs)
+    refusals = (
+        (channels != 1, "COBA ring (2 channels); the kernel commits one "
+                        "CUBA channel"),
+        (not izh4_only, "neuron models other than IZH4 and generators"),
+        (method != "euler", f"method {method!r}; the kernel integrates "
+                            "euler"),
+        (any(s.plastic or s.stp is not None for s in specs),
+         "plastic or STP projections; their weights change every tick"),
+        (not all(b.pre_start >= 0 and b.post_start >= 0 for b in buckets),
+         "a bucket with a non-contiguous pre or post span"),
     )
+    reason = next((why for refused, why in refusals if refused), "")
     # Tile geometry: the widest streamed buffer must fit _VMEM_TILE_BYTES.
     p_pad = max((-(-b.p // 8) * 8 for b in buckets if b.kind == "dense"),
                 default=8)
@@ -166,7 +176,7 @@ def _plan_fused(
         delays=tuple(delays),
         dense_classes=tuple((pq, tuple(ids)) for pq, ids in classes.items()),
         sparse_ids=tuple(sparse_ids),
-        kernel_ok=kernel_ok,
+        kernel_ok=not reason, kernel_reason=reason,
         tile_q=int(tile_q), tile_r=int(tile_r),
     )
 
@@ -219,7 +229,7 @@ class NetStatic:
     # -- execution strategy (see repro.core.backend) --------------------------
     backend: str = "xla"  # "xla" | "pallas" | "fused"
     propagation: str = "packed"  # "packed" | "sparse" | "auto" | "loop"
-    pallas_interpret: bool = True  # interpret-mode kernels (CPU containers)
+    pallas_interpret: bool = True  # interpret-mode kernels (never on a TPU)
     izh4_only: bool = False  # network is IZH4 + generators only (kernel-able)
     event_gated: bool = True  # skip a bucket's matmul when its pres are silent
     buckets: tuple[BucketSpec, ...] = ()
@@ -237,8 +247,9 @@ class NetStatic:
     # Compile-time tile plan for backend="fused" (None otherwise).
     fused: FusedPlan | None = None
     # True when the fused tick runs as ONE Pallas program (TPU, or
-    # REPRO_PALLAS_INTERPRET=1 forcing interpret mode); False falls back
-    # to the single-dispatch XLA expression of the same plan.
+    # REPRO_PALLAS_INTERPRET=1 forcing interpret mode); False runs the
+    # single-dispatch XLA expression of the same plan (``fused.kernel_reason``
+    # says why when the net itself is ineligible).
     fused_kernel: bool = False
     # Compiled in-scan monitor specs (repro.telemetry); the engine lowers
     # them into scan-carry accumulators when run(record="monitors"/"both").
@@ -446,8 +457,7 @@ class NetworkBuilder:
             raise ValueError(
                 "homeostasis_period set but no connection has a "
                 "HomeostasisConfig")
-        if pallas_interpret is None:
-            pallas_interpret = jax.default_backend() != "tpu"
+        pallas_interpret = kops.resolve_interpret(pallas_interpret)
         if isinstance(policy, str):
             policy = get_policy(policy)
         ledger = ledger if ledger is not None else MemoryLedger()
@@ -716,15 +726,13 @@ class NetworkBuilder:
         fused = None
         fused_kernel = False
         if backend == "fused":
-            from repro.kernels.ops import env_interpret, on_tpu
-
             fused = _plan_fused(buckets, tuple(specs), channels,
                                 izh4_only, method)
             # The Pallas program engages on TPU (native lowering) or when
-            # CI forces interpret execution; the default CPU container
-            # takes the single-dispatch XLA expression of the same plan.
+            # CI forces interpret execution; other CPU runs take the
+            # single-dispatch XLA expression of the same plan.
             fused_kernel = fused.kernel_ok and (
-                on_tpu() or bool(env_interpret()))
+                kops.on_tpu() or bool(kops.env_interpret()))
 
         static = NetStatic(
             n=n, ring_len=ring_len, ring_channels=channels, dt=dt,

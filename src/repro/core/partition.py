@@ -966,7 +966,7 @@ def run_partitioned_mesh(static, plan: PartitionPlan, pparams,
 
     Non-plastic/CUBA networks only (enforced at plan time). Returns
     ``(final_global_state, outputs)`` like :func:`run_partitioned`."""
-    from repro.core.distributed import _SHARD_MAP_NOCHECK, core_mesh, shard_map
+    from repro.core.distributed import core_mesh
     from jax.sharding import PartitionSpec as P
 
     _check_record(record)
@@ -1048,13 +1048,13 @@ def run_partitioned_mesh(static, plan: PartitionPlan, pparams,
     want_raster = record == "raster"
 
     @jax.jit
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
               in_specs=(jax.tree.map(lambda _: P(axis), neurons_st),
                         P(axis), P(), P()),
               out_specs=(jax.tree.map(lambda _: P(axis), neurons_st),
                          P(axis),
                          P(None, axis) if want_raster else P()),
-              **_SHARD_MAP_NOCHECK)
+              check_vma=False)
     def shard_run(neurons_in, ring_in, gu_in, t0):
         ci = jax.lax.axis_index(axis)
         neurons = jax.tree.map(lambda x: x[0], neurons_in)

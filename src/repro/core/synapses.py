@@ -316,15 +316,17 @@ def propagate(
 ) -> jax.Array:
     """Synaptic current contribution of this projection: [post_size] f32.
 
-    fp16 weights are up-cast to f32 *at the matmul* (softfp analogue); the
-    Pallas ``syn_matmul`` kernel fuses this decode into the MXU tiles on TPU.
+    fp16 weights are up-cast to f32 *at the matmul* (softfp analogue),
+    which runs at ``Precision.HIGHEST``: a TPU's default f32 matmul rounds
+    its inputs to bf16, and weights such as Synfire4-mini's ``-6.667`` do
+    not survive that.
     """
     pre_spikes = spikes[spec.pre_slice].astype(jnp.float32)
     if stp_state is not None and spec.stp is not None:
         # Effective weight scale A = u⁺·x per presynaptic neuron.
         pre_spikes = pre_spikes * (stp_state.u * stp_state.x)
     w = params.weight.astype(jnp.float32)
-    return pre_spikes @ w
+    return jnp.dot(pre_spikes, w, precision=jax.lax.Precision.HIGHEST)
 
 
 def stp_update(

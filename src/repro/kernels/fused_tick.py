@@ -15,32 +15,45 @@ Neuron-indexed vectors are ``[1, Np]`` rows (``Np`` = N padded to the
 the ring is ``[L, Np]``.  Dense bucket images are stacked into one
 ``[Bd, Pp, Qp]`` operand streamed in ``(1, Pp, tile_q)`` column tiles; CSR
 buckets concatenate their fan-in rows into ``[R, Fp]`` index/weight tables
-streamed in ``(tile_r, Fp)`` row tiles — the in-kernel ``take`` subsumes
-the standalone ``syn_gather`` lowering.  A scalar-prefetch schedule
+streamed in ``(tile_r, Fp)`` row tiles — the in-kernel ``lane_take``
+subsumes the standalone ``syn_gather`` lowering.  A scalar-prefetch schedule
 (``meta[i] = (kind, sel, pre_start, post_off, kpos, qt)``) drives both the
 BlockSpec index maps (which weight tile to DMA for grid step ``i``) and
-the in-kernel placement of each tile's drive.
+the in-kernel placement of each tile's drive.  Every window offset in the
+schedule is a multiple of 128: ``assemble_kernel`` shifts each bucket's
+image (or CSR rows) inside its tile by the bucket's offset from the lane
+boundary, so the kernel only ever slices lane-aligned windows.
 
 Grid step 0 runs the tick prologue (ring read → ``i_syn``, slot zeroing,
 IZH4 update, generator overrides, spike vector, accumulator clear); every
-step accumulates its tile's drive into the per-delay ``[K, Np]``
+step accumulates its tile's drive into the per-delay ``[K, 1, Np]``
 accumulator; the final step runs the epilogue — one ring row
 read-add-write per DISTINCT delay, mirroring the packed path's commit
 exactly.
+
+Dtypes at the boundary (what Mosaic accepts on TPU v5e): state, ring and
+weights cross as f32 and bool rows as int32.  The storage dtype's rounding
+is applied in-kernel at the same points the XLA path casts
+(``_storage_round``; v5e has no f32→f16 convert, so fp16 rounds by bit
+arithmetic) — the f32 carriers only ever hold storage-representable values,
+and the wrapper's casts back to storage are exact.
 
 Bitwise stance (same as the rest of ``kernels/``): padding rows/columns
 carry weight ``+0.0`` so their contributions are exact zeros, and the
 engine's accumulator cells are never ``-0.0`` — adding a padded tile is a
 bitwise no-op.  With the exactly-representable weight tables the Synfire
-configs use, any accumulation order gives the exact sum, so the kernel
-raster is bit-identical to the XLA fused/packed/sparse paths (asserted in
-``tests/test_backends.py``); goldens validate the kernel against the
+configs use, any accumulation order gives the exact sum (the MXU contraction
+runs at ``Precision.HIGHEST``), so the kernel raster is bit-identical to the
+XLA fused/packed/sparse paths (asserted in ``tests/test_fused.py`` and, on
+a TPU, by ``chip_smoke.py``); goldens validate the kernel against the
 independent ``kernels.ref.fused_tick_ref`` oracle off the lane grid.
 
-Eligibility is compiled into ``NetStatic.fused_kernel``: IZH4+generators
-only, Euler, CUBA single-channel ring, no plasticity/STP, contiguous
-bucket spans — on TPU it engages natively; ``REPRO_PALLAS_INTERPRET=1``
-forces the interpreted kernel elsewhere (CI / goldens).
+Eligibility is compiled into ``NetStatic.fused_kernel`` (see
+``network._plan_fused``, which records the reason when a net is refused):
+IZH4+generators only, Euler, CUBA single-channel ring, no plasticity/STP,
+contiguous bucket spans.  The kernel engages on a TPU;
+``REPRO_PALLAS_INTERPRET=1`` forces the interpreted kernel on other
+backends (CI / goldens).
 """
 from __future__ import annotations
 
@@ -53,8 +66,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.syn_gather import lane_take
+
 LANE = 128
-SUBLANE = 8
+_MAX_TILE_R = 512
 
 # meta column indices (schedule rows, scalar-prefetched to SMEM)
 _KIND, _SEL, _PRE, _POST, _KPOS, _QT = range(6)
@@ -83,6 +98,12 @@ def _ceil_to(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def _lane_split(start: int) -> tuple[int, int]:
+    """``start`` as (128-aligned window base, shift inside the window)."""
+    base = start // LANE * LANE
+    return base, start - base
+
+
 def assemble_kernel(static, params, packed) -> KernelPayload:
     """Build the kernel payload from the assembled bucket images.
 
@@ -90,7 +111,9 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
     outside the tick scan): dense images pad into the ``[Bd, Pp, Qp]``
     stack, CSR tables globalize their indices (``+ pre_start``) and pad
     rows to the ``tile_r`` grid, and the tile schedule is laid out as one
-    int32 row per grid step."""
+    int32 row per grid step.  Each image sits at its bucket's offset from
+    the lane boundary (pre rows and post columns alike; CSR rows likewise)
+    so that every window the schedule names starts on a lane boundary."""
     plan = static.fused
     buckets = static.buckets
     dense_ids = [bi for bi, b in enumerate(buckets) if b.kind == "dense"]
@@ -99,34 +122,37 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
     f32 = jnp.float32
 
     # -- dense stack geometry --------------------------------------------
-    p_pad = _ceil_to(max((buckets[bi].p for bi in dense_ids), default=1),
-                     SUBLANE)
-    q_max = max((buckets[bi].q for bi in dense_ids), default=1)
+    pre_sh = {bi: _lane_split(buckets[bi].pre_start)[1] for bi in dense_ids}
+    post_sh = {bi: _lane_split(buckets[bi].post_start)[1] for bi in dense_ids}
+    p_pad = _ceil_to(max((pre_sh[bi] + buckets[bi].p for bi in dense_ids),
+                         default=1), LANE)
+    q_max = max((post_sh[bi] + buckets[bi].q for bi in dense_ids), default=1)
     tile_q = LANE * max(1, min(plan.tile_q // LANE, _ceil_to(q_max, LANE) // LANE))
     q_pad = _ceil_to(q_max, tile_q)
     n_qt = q_pad // tile_q
     w_stack = jnp.zeros((max(1, len(dense_ids)), p_pad, q_pad), f32)
     for pos, bi in enumerate(dense_ids):
         b = buckets[bi]
-        w_stack = w_stack.at[pos, :b.p, :b.q].set(packed[bi])
+        r0, c0 = pre_sh[bi], post_sh[bi]
+        w_stack = w_stack.at[pos, r0:r0 + b.p, c0:c0 + b.q].set(packed[bi])
 
     # -- CSR row-tile geometry -------------------------------------------
     f_pad = _ceil_to(
         max((params.bucket_csr_idx[bi].shape[1] for bi in sparse_ids),
             default=1), LANE)
-    tile_r = max(SUBLANE, min(_ceil_to(plan.tile_r, SUBLANE), 512))
-    row_blocks: list[jax.Array] = []
+    tile_r = max(LANE, min(plan.tile_r // LANE * LANE, _MAX_TILE_R))
+    row_blocks: list[tuple[jax.Array, jax.Array]] = []
     csr_meta: list[tuple[int, int]] = []  # (post_off, kpos) per row tile
     for bi in sparse_ids:
         b = buckets[bi]
+        base, sh = _lane_split(b.post_start)
         idx = params.bucket_csr_idx[bi].astype(jnp.int32) + b.pre_start
         w = packed[bi]
-        rows = _ceil_to(b.q, tile_r)
-        idx = jnp.pad(idx, ((0, rows - b.q), (0, f_pad - idx.shape[1])))
-        w = jnp.pad(w, ((0, rows - b.q), (0, f_pad - w.shape[1])))
-        row_blocks.append((idx, w))
+        rows = _ceil_to(sh + b.q, tile_r)
+        pad = ((sh, rows - sh - b.q), (0, f_pad - idx.shape[1]))
+        row_blocks.append((jnp.pad(idx, pad), jnp.pad(w, pad)))
         for rt in range(rows // tile_r):
-            csr_meta.append((b.post_start + rt * tile_r, kpos[b.delay_ms]))
+            csr_meta.append((base + rt * tile_r, kpos[b.delay_ms]))
     if row_blocks:
         csr_idx = jnp.concatenate([ib for ib, _ in row_blocks])
         csr_w = jnp.concatenate([wb for _, wb in row_blocks])
@@ -138,8 +164,10 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
     meta: list[list[int]] = []
     for pos, bi in enumerate(dense_ids):
         b = buckets[bi]
+        pre_base = _lane_split(b.pre_start)[0]
+        post_base = _lane_split(b.post_start)[0]
         for qt in range(n_qt):
-            meta.append([0, pos, b.pre_start, b.post_start + qt * tile_q,
+            meta.append([0, pos, pre_base, post_base + qt * tile_q,
                          kpos[b.delay_ms], qt])
     for rt, (post_off, k) in enumerate(csr_meta):
         meta.append([1, rt, 0, post_off, k, 0])
@@ -156,12 +184,49 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
     )
 
 
+def _bits(x, dtype):
+    return jax.lax.bitcast_convert_type(x, dtype)
+
+
+def _round_f16(x: jax.Array) -> jax.Array:
+    """f32 → the nearest IEEE fp16 value (ties to even), kept in f32.
+
+    Equal to ``x.astype(float16).astype(float32)``, written with integer
+    and f32 arithmetic because Mosaic on v5e has no f32→f16 conversion.
+    Normal range: drop 13 mantissa bits with round-half-even.  Subnormal
+    range (``|x| < 2**-14``): the fp16 quantum there is ``2**-24``, the f32
+    ulp of 0.5, so ``(|x| + 0.5) - 0.5`` rounds to it exactly.  Overflow
+    goes to inf; the sign (incl. of zero) and NaN pass through."""
+    b = _bits(x, jnp.int32)
+    mag = b & 0x7FFFFFFF
+    normal = _bits((mag + 0xFFF + ((mag >> 13) & 1)) & ~0x1FFF, jnp.float32)
+    ax = _bits(mag, jnp.float32)
+    r = jnp.where(ax < 2.0 ** -14, (ax + 0.5) - 0.5, normal)
+    r = jnp.where(r > 65504.0, jnp.inf, r)
+    out = _bits(_bits(r, jnp.int32) | (b & jnp.int32(-2 ** 31)), jnp.float32)
+    return jnp.where(x != x, x, out)
+
+
+def _storage_round(dtype):
+    """In-kernel rounding of an f32 value to the storage ``dtype`` (result
+    stays f32: it is the carrier the kernel reads and writes)."""
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.float32:
+        return lambda x: x
+    if dtype == jnp.float16:
+        return _round_f16
+    if dtype == jnp.bfloat16:
+        return lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    raise TypeError(f"fused tick has no storage rounding for {dtype}")
+
+
 def _tick_kernel(m_ref, t_ref, v_ref, u_ref, ring_ref, gen_ref, isg_ref,
                  a_ref, b_ref, c_ref, d_ref, w_ref, ci_ref, cw_ref,
                  vo_ref, uo_ref, so_ref, io_ref, ro_ref, acc_ref, *,
                  ring_len: int, dt: float, substeps: int,
-                 delays: tuple[int, ...], n_steps: int, n_pad: int,
-                 p_pad: int, tile_q: int, tile_r: int):
+                 delays: tuple[int, ...], n_steps: int,
+                 p_pad: int, tile_q: int, tile_r: int,
+                 state_round, ring_round):
     f32 = jnp.float32
     i = pl.program_id(0)
     t = t_ref[0]
@@ -170,63 +235,56 @@ def _tick_kernel(m_ref, t_ref, v_ref, u_ref, ring_ref, gen_ref, isg_ref,
     def _prologue():
         slot = jax.lax.rem(t, ring_len)
         ro_ref[...] = ring_ref[...]
-        row = pl.load(ring_ref, (pl.ds(slot, 1), pl.ds(0, n_pad)))
-        i_syn = row.astype(f32)
+        i_syn = ring_ref[pl.ds(slot, 1), :]
         io_ref[...] = i_syn
-        pl.store(ro_ref, (pl.ds(slot, 1), pl.ds(0, n_pad)),
-                 jnp.zeros_like(row))
+        ro_ref[pl.ds(slot, 1), :] = jnp.zeros_like(i_syn)
         # IZH4 integration — identical expression tree to kernels.ref.
         # izh4_ref / the engine fast path, so state dtypes round-trip
         # bit-identically (f32 math, storage-dtype writeback).
-        v = v_ref[...].astype(f32)
-        u = u_ref[...].astype(f32)
+        v = v_ref[...]
+        u = u_ref[...]
         a = a_ref[...]
         b = b_ref[...]
         c = c_ref[...]
         d = d_ref[...]
         h = dt / substeps
         for _ in range(substeps):
-            dv = 0.04 * v * v + 5.0 * v + 140.0 - u + i_syn
+            dv = (0.04 * v + 5.0) * v + 140.0 - u + i_syn
             du = a * (b * v - u)
             v = v + h * dv
             u = u + h * du
         spiked = v >= 30.0
         v = jnp.where(spiked, c, v)
         u = jnp.where(spiked, u + d, u)
-        v1 = v.astype(vo_ref.dtype)
-        u1 = u.astype(uo_ref.dtype)
         # Generator overrides in the engine's exact order (storage-dtype
-        # round-trip between the reset and the hold-at-rest writes).
-        isg = isg_ref[...]
-        vo_ref[...] = jnp.where(isg, c, v1.astype(f32)).astype(vo_ref.dtype)
-        uo_ref[...] = jnp.where(isg, 0.0, u1.astype(f32)).astype(uo_ref.dtype)
-        so_ref[...] = jnp.where(isg, gen_ref[...], spiked)
+        # rounding between the reset and the hold-at-rest writes).
+        isg = isg_ref[...] != 0
+        vo_ref[...] = state_round(jnp.where(isg, c, state_round(v)))
+        uo_ref[...] = state_round(jnp.where(isg, 0.0, state_round(u)))
+        so_ref[...] = jnp.where(isg, gen_ref[...], spiked.astype(jnp.int32))
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kind = m_ref[i, _KIND]
 
     @pl.when(kind == 0)
     def _dense_tile():
-        ps = m_ref[i, _PRE]
-        po = m_ref[i, _POST]
+        ps = pl.multiple_of(m_ref[i, _PRE], LANE)
+        po = pl.multiple_of(m_ref[i, _POST], LANE)
         k = m_ref[i, _KPOS]
-        pre = pl.load(so_ref, (pl.ds(0, 1), pl.ds(ps, p_pad))).astype(f32)
+        pre = so_ref[:, pl.ds(ps, p_pad)].astype(f32)
         drive = jax.lax.dot_general(
-            pre, w_ref[...][0], (((1,), (0,)), ((), ())),
+            pre, w_ref[0], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=f32)  # [1, tile_q]
-        cur = pl.load(acc_ref, (pl.ds(k, 1), pl.ds(po, tile_q)))
-        pl.store(acc_ref, (pl.ds(k, 1), pl.ds(po, tile_q)), cur + drive)
+        acc_ref[k, :, pl.ds(po, tile_q)] += drive
 
     @pl.when(kind == 1)
     def _csr_tile():
-        po = m_ref[i, _POST]
+        po = pl.multiple_of(m_ref[i, _POST], LANE)
         k = m_ref[i, _KPOS]
-        spk = so_ref[...][0].astype(f32)  # [Np] resident spike row
-        g = jnp.take(spk, ci_ref[...], axis=0)  # in-kernel gather
+        g = lane_take(so_ref, ci_ref[...])  # in-kernel fan-in gather
         drive = (g * cw_ref[...]).sum(axis=1)  # [tile_r]
-        cur = pl.load(acc_ref, (pl.ds(k, 1), pl.ds(po, tile_r)))
-        pl.store(acc_ref, (pl.ds(k, 1), pl.ds(po, tile_r)),
-                 cur + drive[None])
+        acc_ref[k, :, pl.ds(po, tile_r)] += drive[None]
 
     @pl.when(i == n_steps - 1)
     def _epilogue():
@@ -234,10 +292,9 @@ def _tick_kernel(m_ref, t_ref, v_ref, u_ref, ring_ref, gen_ref, isg_ref,
         # ring storage dtype) as the packed path's per-delay commits.
         for k, dly in enumerate(delays):
             dslot = jax.lax.rem(t + dly, ring_len)
-            rrow = pl.load(ro_ref, (pl.ds(dslot, 1), pl.ds(0, n_pad)))
-            arow = pl.load(acc_ref, (pl.ds(k, 1), pl.ds(0, n_pad)))
-            pl.store(ro_ref, (pl.ds(dslot, 1), pl.ds(0, n_pad)),
-                     rrow + arow.astype(rrow.dtype))
+            rrow = ro_ref[pl.ds(dslot, 1), :]
+            arow = acc_ref[k]
+            ro_ref[pl.ds(dslot, 1), :] = ring_round(rrow + ring_round(arow))
 
 
 def fused_tick(static, v, u, ring, gen_row, is_gen, a, b, c, d, t,
@@ -255,27 +312,22 @@ def fused_tick(static, v, u, ring, gen_row, is_gen, a, b, c, d, t,
     np_ = kp.n_pad
     f32 = jnp.float32
 
-    def row(x, dtype=None):
-        x = x if dtype is None else x.astype(dtype)
-        return jnp.pad(x, (0, np_ - n))[None]
+    def row(x, dtype=f32):
+        return jnp.pad(x.astype(dtype), (0, np_ - n))[None]
 
-    ring_p = jnp.pad(ring, ((0, 0), (0, np_ - n)))
+    ring_p = jnp.pad(ring.astype(f32), ((0, 0), (0, np_ - n)))
     delays = static.fused.delays
     k_delays = max(1, len(delays))
+    vec = pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0))
+    whole_ring = pl.BlockSpec(ring_p.shape, lambda i, m, tt: (0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # meta schedule + tick counter
         grid=(kp.n_steps,),
         in_specs=[
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # v
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # u
-            pl.BlockSpec(ring_p.shape, lambda i, m, tt: (0, 0)),  # ring
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # gen_row
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # is_gen
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # a
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # b
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # c
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # d
+            vec, vec, whole_ring,  # v, u, ring
+            vec, vec,  # gen_row, is_gen
+            vec, vec, vec, vec,  # a, b, c, d
             # streamed tiles: the index maps read the prefetched schedule,
             # clamping to tile 0 on grid steps of the other kind (the
             # pipeline still double-buffers the matching steps' DMAs).
@@ -291,33 +343,31 @@ def fused_tick(static, v, u, ring, gen_row, is_gen, a, b, c, d, t,
                          lambda i, m, tt: (jnp.where(m[i, _KIND] == 1,
                                                      m[i, _SEL], 0), 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # v'
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # u'
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # spikes
-            pl.BlockSpec((1, np_), lambda i, m, tt: (0, 0)),  # i_syn
-            pl.BlockSpec(ring_p.shape, lambda i, m, tt: (0, 0)),  # ring'
-            pl.BlockSpec((k_delays, np_), lambda i, m, tt: (0, 0)),  # acc
-        ],
+        out_specs=[vec, vec, vec, vec, whole_ring],  # v', u', spikes, i_syn, ring'
+        # per-delay accumulator rows; the delay is the leading (untiled)
+        # dim, so picking row k at run time needs no sublane alignment
+        scratch_shapes=[pltpu.VMEM((k_delays, 1, np_), f32)],
     )
     kern = functools.partial(
         _tick_kernel, ring_len=static.ring_len, dt=static.dt,
         substeps=static.substeps, delays=delays, n_steps=kp.n_steps,
-        n_pad=np_, p_pad=kp.p_pad, tile_q=kp.tile_q, tile_r=kp.tile_r)
-    v_o, u_o, sp_o, isyn_o, ring_o, _acc = pl.pallas_call(
+        p_pad=kp.p_pad, tile_q=kp.tile_q, tile_r=kp.tile_r,
+        state_round=_storage_round(v.dtype),
+        ring_round=_storage_round(ring.dtype))
+    v_o, u_o, sp_o, isyn_o, ring_o = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((1, np_), v.dtype),
-            jax.ShapeDtypeStruct((1, np_), u.dtype),
-            jax.ShapeDtypeStruct((1, np_), jnp.bool_),
             jax.ShapeDtypeStruct((1, np_), f32),
-            jax.ShapeDtypeStruct(ring_p.shape, ring.dtype),
-            jax.ShapeDtypeStruct((k_delays, np_), f32),
+            jax.ShapeDtypeStruct((1, np_), f32),
+            jax.ShapeDtypeStruct((1, np_), jnp.int32),
+            jax.ShapeDtypeStruct((1, np_), f32),
+            jax.ShapeDtypeStruct(ring_p.shape, f32),
         ],
         interpret=interpret,
     )(kp.meta, t.reshape(1).astype(jnp.int32),
-      row(v), row(u), ring_p, row(gen_row), row(is_gen),
-      row(a, f32), row(b, f32), row(c, f32), row(d, f32),
+      row(v), row(u), ring_p, row(gen_row, jnp.int32),
+      row(is_gen, jnp.int32), row(a), row(b), row(c), row(d),
       kp.w_stack, kp.csr_idx, kp.csr_w)
-    return (v_o[0, :n], u_o[0, :n], sp_o[0, :n], ring_o[:, :n],
+    return (v_o[0, :n].astype(v.dtype), u_o[0, :n].astype(u.dtype),
+            sp_o[0, :n] != 0, ring_o[:, :n].astype(ring.dtype),
             isyn_o[0, :n])

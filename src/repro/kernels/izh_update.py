@@ -1,10 +1,12 @@
 """Pallas TPU kernel: fused IZH4 neuron update + spike detection + reset.
 
 The MCU inner loop the paper profiles — per-tick Izhikevich integration over
-all neurons — as a single fused VPU pass: load (v, u) in the storage dtype
-(fp16 under the paper's policy), integrate in f32, detect/reset spikes, store
-back. Fusion avoids materializing the intermediate derivative arrays in HBM;
-arithmetic intensity rises from ~0.5 to ~3 flops/byte at fp16 storage.
+all neurons — as a single fused VPU pass: integrate (v, u) in f32,
+detect/reset spikes, store back. State crosses the kernel boundary as f32
+and spikes as int32 (Mosaic on v5e loads neither 16-bit float nor bool
+tiles); the wrapper rounds the result to the storage dtype (fp16 under the
+paper's policy) once, exactly where ``kernels.ref.izh4_ref`` rounds.
+Fusion avoids materializing the intermediate derivative arrays in HBM.
 
 Layout: neuron arrays are viewed as [rows, 128] (VPU lane width) and tiled
 in (block_rows, 128) VMEM blocks.
@@ -23,9 +25,9 @@ DEFAULT_BLOCK_ROWS = 64  # (64, 128) f32 blocks = 32 KiB — comfortably VMEM
 
 def _izh4_kernel(v_ref, u_ref, i_ref, a_ref, b_ref, c_ref, d_ref,
                  vo_ref, uo_ref, s_ref, *, dt: float, substeps: int):
-    v = v_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    i_syn = i_ref[...].astype(jnp.float32)
+    v = v_ref[...]
+    u = u_ref[...]
+    i_syn = i_ref[...]
     a = a_ref[...]
     b = b_ref[...]
     c = c_ref[...]
@@ -35,16 +37,16 @@ def _izh4_kernel(v_ref, u_ref, i_ref, a_ref, b_ref, c_ref, d_ref,
         # Simultaneous (dv, du) from the same (v, u) — identical expression
         # tree to neurons._derivs so the pallas backend is bit-exact with
         # the xla reference path.
-        dv = 0.04 * v * v + 5.0 * v + 140.0 - u + i_syn
+        dv = (0.04 * v + 5.0) * v + 140.0 - u + i_syn
         du = a * (b * v - u)
         v = v + h * dv
         u = u + h * du
     spiked = v >= 30.0
     v = jnp.where(spiked, c, v)
     u = jnp.where(spiked, u + d, u)
-    vo_ref[...] = v.astype(vo_ref.dtype)
-    uo_ref[...] = u.astype(uo_ref.dtype)
-    s_ref[...] = spiked
+    vo_ref[...] = v
+    uo_ref[...] = u
+    s_ref[...] = spiked.astype(jnp.int32)
 
 
 def izh4_update(v, u, i_syn, a, b, c, d, *, dt: float = 1.0, substeps: int = 2,
@@ -55,13 +57,11 @@ def izh4_update(v, u, i_syn, a, b, c, d, *, dt: float = 1.0, substeps: int = 2,
     n_pad = -n % per_block
     rows = (n + n_pad) // LANE
 
-    def prep(x, dtype=None):
-        x = jnp.pad(x, (0, n_pad))
-        return x.reshape(rows, LANE).astype(dtype or x.dtype)
+    def prep(x):
+        x = jnp.pad(x.astype(jnp.float32), (0, n_pad))
+        return x.reshape(rows, LANE)
 
-    args = (prep(v), prep(u), prep(i_syn, jnp.float32),
-            prep(a, jnp.float32), prep(b, jnp.float32),
-            prep(c, jnp.float32), prep(d, jnp.float32))
+    args = tuple(prep(x) for x in (v, u, i_syn, a, b, c, d))
     grid = (rows // block_rows,)
     spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     vo, uo, sp = pl.pallas_call(
@@ -70,10 +70,11 @@ def izh4_update(v, u, i_syn, a, b, c, d, *, dt: float = 1.0, substeps: int = 2,
         in_specs=[spec] * 7,
         out_specs=[spec] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((rows, LANE), v.dtype),
-            jax.ShapeDtypeStruct((rows, LANE), u.dtype),
-            jax.ShapeDtypeStruct((rows, LANE), jnp.bool_),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         ],
         interpret=interpret,
     )(*args)
-    return (vo.reshape(-1)[:n], uo.reshape(-1)[:n], sp.reshape(-1)[:n])
+    return (vo.reshape(-1)[:n].astype(v.dtype),
+            uo.reshape(-1)[:n].astype(u.dtype), sp.reshape(-1)[:n] != 0)

@@ -1,8 +1,9 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; everywhere else (this CPU
-container, unit tests) they execute through the Pallas interpreter so the
-kernel *logic* is validated bit-for-bit against ``ref.py``. ``use_pallas``
+On TPU the compiled kernels run natively (interpret mode there is an
+error, :func:`resolve_interpret`); everywhere else (CPU hosts, unit
+tests) they execute through the Pallas interpreter so the kernel *logic*
+is validated bit-for-bit against ``ref.py``. ``use_pallas``
 lets the models swap between the XLA reference path (used by the dry-run,
 which lowers for the production mesh) and the kernel path.
 """
@@ -20,10 +21,16 @@ from repro.kernels import izh_update as _izh
 from repro.kernels import stdp_update as _stdp
 from repro.kernels import syn_matmul as _syn
 
-__all__ = ["on_tpu", "env_interpret", "izh4_update", "syn_matmul",
+__all__ = ["on_tpu", "env_interpret", "resolve_interpret",
+           "InterpretOnTPUError", "izh4_update", "syn_matmul",
            "flash_attention", "stdp_update", "fused_tick"]
 
 _FALSY = ("", "0", "false", "no", "off")
+
+
+class InterpretOnTPUError(RuntimeError):
+    """Interpret-mode Pallas was requested on a TPU backend — the chip's
+    path must run the compiled kernels, never the interpreter."""
 
 
 def on_tpu() -> bool:
@@ -33,23 +40,40 @@ def on_tpu() -> bool:
 def env_interpret() -> bool | None:
     """Tri-state ``REPRO_PALLAS_INTERPRET`` override: ``None`` when the
     variable is unset (auto-detect from the backend), else the parsed
-    bool — ``1`` forces interpret mode everywhere (CI exercising the
-    kernel code path deterministically), ``0`` forces it off."""
+    bool — ``1`` forces interpret mode (CI exercising the kernel code
+    path deterministically off-TPU), ``0`` forces it off."""
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is None:
         return None
     return env.strip().lower() not in _FALSY
 
 
+def resolve_interpret(requested: bool | None = None) -> bool:
+    """Whether Pallas kernels run in interpret mode.
+
+    ``requested`` (e.g. ``compile(pallas_interpret=...)``) wins over
+    ``REPRO_PALLAS_INTERPRET``; with neither set, kernels are interpreted
+    everywhere except on a TPU.  On a TPU backend a request for interpret
+    mode — from either source — raises :class:`InterpretOnTPUError`
+    instead of silently running the interpreter on the chip."""
+    if requested is None:
+        requested = env_interpret()
+    if on_tpu():
+        if requested:
+            raise InterpretOnTPUError(
+                "interpret-mode Pallas requested on a TPU backend (via "
+                "pallas_interpret=True or REPRO_PALLAS_INTERPRET) — the chip "
+                "runs the compiled kernels; unset the override")
+        return False
+    return True if requested is None else requested
+
+
 @functools.cache
 def _interpret() -> bool:
     """Evaluated once per process (the backend never changes mid-run;
     re-querying ``jax.default_backend()`` on every jit'd dispatch was
-    wasted work), overridable via ``REPRO_PALLAS_INTERPRET``."""
-    env = env_interpret()
-    if env is not None:
-        return env
-    return not on_tpu()
+    wasted work)."""
+    return resolve_interpret()
 
 
 @partial(jax.jit, static_argnames=("dt", "substeps"))

@@ -15,8 +15,8 @@ def izh4_ref(v, u, i_syn, a, b, c, d, *, dt: float = 1.0, substeps: int = 2):
     for _ in range(substeps):
         # Simultaneous derivatives (CARLsim evaluates dv and du from the
         # same pre-step state) — keeps the kernel bit-exact with the
-        # engine's neurons._derivs euler path.
-        dv = 0.04 * v * v + 5.0 * v + 140.0 - u + i_syn
+        # engine's neurons._derivs euler path (same factored dv, see there).
+        dv = (0.04 * v + 5.0) * v + 140.0 - u + i_syn
         du = a * (b * v - u)
         v = v + h * dv
         u = u + h * du
@@ -30,6 +30,7 @@ def syn_matmul_ref(x, w):
     """x [M, K] @ w [K, N], storage-dtype weights decoded to f32 (softfp)."""
     return jnp.dot(
         x.astype(jnp.float32), w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -103,6 +104,7 @@ def fused_tick_ref(v, u, ring, gen_row, is_gen, a, b, c, d, t, *,
     for ps, qs, dly, w in dense:
         p, q = w.shape
         drive = jnp.dot(sf[ps:ps + p], w.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=f32)
         a_ = acc.get(dly, jnp.zeros((n,), f32))
         acc[dly] = a_.at[qs:qs + q].add(drive)

@@ -21,9 +21,12 @@ dense update at the corresponding cells — unlike the propagation sum there
 is no accumulation-order freedom for padding to perturb.
 
 Layout mirrors ``syn_gather``: grid over post blocks; the pre-sized trace
-and spike rows stay resident in VMEM and are gathered per block; the
-fan-in axis is padded to the 128-lane width (padding lands on
-``valid=False`` cells, which the mask zeroes).
+and spike rows stay resident in VMEM and are gathered per block with
+``lane_take``; the fan-in axis is padded to the 128-lane width (padding
+lands on ``valid=False`` cells, which the mask zeroes). Weights cross the
+kernel boundary as f32 and the validity mask as int32 (Mosaic on v5e
+loads neither 16-bit float nor bool tiles); the one storage-dtype
+rounding happens after the call, exactly where the oracle rounds.
 """
 from __future__ import annotations
 
@@ -33,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.syn_gather import lane_take
+
 LANE = 128
 DEFAULT_BLOCK_Q = 256  # post neurons per grid step
 
@@ -40,20 +45,17 @@ DEFAULT_BLOCK_Q = 256  # post neurons per grid step
 def _stdp_gather_kernel(w_ref, idx_ref, valid_ref, pre_t_ref, pre_s_ref,
                         post_t_ref, post_s_ref, o_ref, *,
                         a_plus, a_minus, w_min, w_max):
-    w = w_ref[...].astype(jnp.float32)  # [bq, Fp]
+    w = w_ref[...]  # [bq, Fp] f32
     idx = idx_ref[...]  # [bq, Fp] int32 (padding -> 0, masked below)
-    valid = valid_ref[...]  # [bq, Fp] bool
-    pre_t = pre_t_ref[...][0]  # [Pp] f32 pre trace (resident)
-    pre_s = pre_s_ref[...][0]  # [Pp] f32 pre spikes
-    post_t = post_t_ref[...].reshape(-1, 1)  # [bq, 1]
-    post_s = post_s_ref[...].reshape(-1, 1)  # [bq, 1]
+    valid = valid_ref[...] != 0  # [bq, Fp] int32 flags
+    post_t = post_t_ref[...]  # [bq, 1]
+    post_s = post_s_ref[...]  # [bq, 1]
     # a⁺·(pre_t[idx] · post_s) − a⁻·(pre_s[idx] · post_t): association
     # matches the jnp oracle (scalar × (gather × broadcast)) bit-for-bit.
-    ltp = a_plus * (jnp.take(pre_t, idx, axis=0) * post_s)
-    ltd = a_minus * (jnp.take(pre_s, idx, axis=0) * post_t)
+    ltp = a_plus * (lane_take(pre_t_ref, idx) * post_s)
+    ltd = a_minus * (lane_take(pre_s_ref, idx) * post_t)
     w = jnp.clip(w + ltp - ltd, w_min, w_max)
-    w = jnp.where(valid, w, 0.0)
-    o_ref[...] = w.astype(o_ref.dtype)
+    o_ref[...] = jnp.where(valid, w, 0.0)
 
 
 def stdp_gather(w, idx, valid, pre_trace, post_trace, pre_spikes,
@@ -72,9 +74,9 @@ def stdp_gather(w, idx, valid, pre_trace, post_trace, pre_spikes,
     fp = _ceil_to(f, LANE)
     pp = _ceil_to(p, LANE)
     qp = -q % bq
-    wp = jnp.pad(w, ((0, qp), (0, fp - f)))
+    wp = jnp.pad(w.astype(jnp.float32), ((0, qp), (0, fp - f)))
     idxp = jnp.pad(idx.astype(jnp.int32), ((0, qp), (0, fp - f)))
-    validp = jnp.pad(valid, ((0, qp), (0, fp - f)))
+    validp = jnp.pad(valid.astype(jnp.int32), ((0, qp), (0, fp - f)))
     pre_t = jnp.pad(pre_trace.astype(jnp.float32), (0, pp - p))[None, :]
     pre_s = jnp.pad(pre_spikes.astype(jnp.float32), (0, pp - p))[None, :]
     post_t = jnp.pad(post_trace.astype(jnp.float32), (0, qp))[:, None]
@@ -94,10 +96,10 @@ def stdp_gather(w, idx, valid, pre_trace, post_trace, pre_spikes,
             pl.BlockSpec((bq, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bq, fp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((q + qp, fp), w.dtype),
+        out_shape=jax.ShapeDtypeStruct((q + qp, fp), jnp.float32),
         interpret=interpret,
     )(wp, idxp, validp, pre_t, pre_s, post_t, post_s)
-    return out[:q, :f]
+    return out[:q, :f].astype(w.dtype)
 
 
 def _ceil_to(x: int, mult: int) -> int:

@@ -4,7 +4,9 @@ Fuses the two rank-1 updates (LTP outer product + LTD outer product), the
 clip, and the mask into a single pass over the fp16 weight matrix — CARLsim
 walks synapses twice for this; one fused pass halves the weight-matrix
 traffic, which dominates (the paper: synaptic memory is *the* limiting
-factor).
+factor). Weights cross the kernel boundary as f32 and the mask as int32
+(Mosaic on v5e loads neither 16-bit float nor bool tiles); the result is
+rounded to the storage dtype once, after the call.
 """
 from __future__ import annotations
 
@@ -26,8 +28,7 @@ def _stdp_kernel(w_ref, mask_ref, pre_t_ref, post_t_ref, pre_s_ref,
     # jnp oracle (scalar × outer product) so results are bit-identical.
     w = w + a_plus * (pre_t * post_s) - a_minus * (pre_s * post_t)
     w = jnp.clip(w, w_min, w_max)
-    w = jnp.where(mask_ref[...], w, 0.0)
-    o_ref[...] = w.astype(o_ref.dtype)
+    o_ref[...] = jnp.where(mask_ref[...] != 0, w, 0.0)
 
 
 def stdp_update(w, mask, pre_trace, post_trace, pre_spikes, post_spikes, *,
@@ -39,8 +40,8 @@ def stdp_update(w, mask, pre_trace, post_trace, pre_spikes, post_spikes, *,
     bp = min(block_p, _ceil_to(p, 8))
     bq = min(block_q, _ceil_to(q, 128))
     pp, qp = -p % bp, -q % bq
-    wp = jnp.pad(w, ((0, pp), (0, qp)))
-    maskp = jnp.pad(mask, ((0, pp), (0, qp)))
+    wp = jnp.pad(w.astype(jnp.float32), ((0, pp), (0, qp)))
+    maskp = jnp.pad(mask.astype(jnp.int32), ((0, pp), (0, qp)))
     pre_t = jnp.pad(pre_trace.astype(jnp.float32), (0, pp)).reshape(-1, 1)
     post_t = jnp.pad(post_trace.astype(jnp.float32), (0, qp)).reshape(1, -1)
     pre_s = jnp.pad(pre_spikes.astype(jnp.float32), (0, pp)).reshape(-1, 1)
@@ -58,10 +59,10 @@ def stdp_update(w, mask, pre_trace, post_trace, pre_spikes, post_spikes, *,
             pl.BlockSpec((1, bq), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bp, bq), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((p + pp, q + qp), w.dtype),
+        out_shape=jax.ShapeDtypeStruct((p + pp, q + qp), jnp.float32),
         interpret=interpret,
     )(wp, maskp, pre_t, post_t, pre_s, post_s)
-    return out[:p, :q]
+    return out[:p, :q].astype(w.dtype)
 
 
 def _ceil_to(x: int, mult: int) -> int:

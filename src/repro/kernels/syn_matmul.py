@@ -1,11 +1,12 @@
-"""Pallas TPU kernel: fp16-storage matmul with fused decode + f32 accumulate.
+"""Pallas TPU kernel: blocked f32 matmul with f32 accumulate.
 
-The paper's FP16 technique at the MXU: weights stay in IEEE fp16 in
-HBM/VMEM and are up-cast *inside the kernel tile* right before the MXU
-issue, accumulating in f32 (the softfp promotion, but free on the MXU since
-it natively multiplies bf16/fp16 inputs into an f32 accumulator). Used for
-SNN spike propagation (spikes_f32 @ W_fp16) and as the LM projection matmul
-with fp16-stored parameters.
+Used for SNN spike propagation (``spikes_f32 @ W``) on the per-op
+``backend="pallas"`` path. Operands of any float storage dtype are
+up-cast to f32 before the call (Mosaic on v5e cannot load 16-bit float
+tiles, and the engine hands over the f32 images it decoded once per run
+anyway). The MXU contraction runs at ``Precision.HIGHEST`` so f32 weights
+that bf16 cannot represent (e.g. Synfire4-mini's ``w_inh=-6.667``) are
+multiplied exactly, as on the XLA path.
 
 Classic 3-D blocked matmul: grid (M/bm, N/bn, K/bk), K innermost, VMEM f32
 scratch accumulator, tile sizes MXU-aligned (128).
@@ -25,22 +26,20 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # fp16 -> f32 decode fused into the MXU feed.
     acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.float32),
-        w_ref[...].astype(jnp.float32),
+        x_ref[...], w_ref[...], precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _emit():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...]
 
 
 def syn_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
                block_k: int = 128, out_dtype=jnp.float32,
                interpret: bool = False):
-    """``x [M, K] @ w [K, N] -> [M, N]`` with storage-dtype w (fp16/bf16).
+    """``x [M, K] @ w [K, N] -> [M, N]`` in f32, cast to ``out_dtype``.
 
     Shapes are zero-padded up to block multiples (zero rows/cols contribute
     nothing to the accumulator).
@@ -51,8 +50,8 @@ def syn_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
     bm, bn, bk = (min(block_m, _ceil_to(m, 8)), min(block_n, _ceil_to(n, 128)),
                   min(block_k, _ceil_to(k, 128)))
     mp, np_, kp = -m % bm, -n % bn, -k % bk
-    xp = jnp.pad(x, ((0, mp), (0, kp)))
-    wp = jnp.pad(w, ((0, kp), (0, np_)))
+    xp = jnp.pad(x.astype(jnp.float32), ((0, mp), (0, kp)))
+    wp = jnp.pad(w.astype(jnp.float32), ((0, kp), (0, np_)))
     mg, ng, kg = (m + mp) // bm, (n + np_) // bn, (k + kp) // bk
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, k_steps=kg),
@@ -62,11 +61,11 @@ def syn_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m + mp, n + np_), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m + mp, n + np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(xp, wp)
-    return out[:m, :n]
+    return out[:m, :n].astype(out_dtype)
 
 
 def _ceil_to(x: int, mult: int) -> int:

@@ -68,7 +68,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
-from repro.core.distributed import _SHARD_MAP_NOCHECK, shard_map
 from repro.core.engine import _run_impl
 from repro.obs import watch as wat
 from repro.obs.metrics import us_per_tick
@@ -673,11 +672,11 @@ def _step_lanes_sharded(static, params, states, gen_keys, active, n_ticks,
         wc = ex[-1] if want_watch else None
         return _lanes_vmap(static, p, s, k, a, n_ticks, record, tc, wc)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(),) + (lane,) * (3 + len(extras)),
         out_specs=(lane,) * n_out,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     return fn(params, states, gen_keys, active, *extras)

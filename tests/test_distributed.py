@@ -101,12 +101,8 @@ class TestShardedSNN:
         def reduce_with(method):
             def f(x):
                 return psum_compressed(x, "pod", method)
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
-            return jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                                     out_specs=P("pod")))
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                         out_specs=P("pod")))
 
         x = jax.random.normal(jax.random.key(0), (4, 64), jnp.float32)
         exact = np.asarray(reduce_with(None)(x))

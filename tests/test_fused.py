@@ -24,6 +24,7 @@ from repro.configs.synfire4 import (
 )
 from repro.core import Engine
 from repro.core.plasticity import HomeostasisConfig
+from repro.kernels import ops as kops
 from repro.kernels.ops import env_interpret
 from repro.serve import Session
 
@@ -136,6 +137,51 @@ class TestFusedKernel:
         net = _build("fp16", "fused", stdp_chain=CHAIN_STDP)
         assert not net.static.fused.kernel_ok
         assert not net.static.fused_kernel
+
+
+class TestChipGuards:
+    """What the chip path refuses: interpret-mode Pallas on a TPU backend
+    (steered here by faking ``ops.on_tpu``), and a megakernel-ineligible
+    net says why at compile time."""
+
+    @pytest.fixture
+    def fake_tpu(self, monkeypatch):
+        monkeypatch.setattr(kops, "on_tpu", lambda: True)
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+
+    def test_interpret_kwarg_raises_on_tpu(self, fake_tpu):
+        with pytest.raises(kops.InterpretOnTPUError, match="TPU"):
+            _build("fp32", "pallas", pallas_interpret=True)
+
+    @pytest.mark.parametrize("backend", ["pallas", "fused"])
+    def test_interpret_env_raises_on_tpu(self, fake_tpu, monkeypatch,
+                                         backend):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+        with pytest.raises(kops.InterpretOnTPUError):
+            kops.resolve_interpret()
+        with pytest.raises(kops.InterpretOnTPUError):
+            _build("fp16", backend)
+
+    def test_tpu_compiles_kernels_natively(self, fake_tpu):
+        net = _build("fp16", "fused")
+        assert net.static.pallas_interpret is False
+        assert net.static.fused_kernel and net.static.fused.kernel_reason == ""
+
+    def test_off_tpu_default_is_interpret(self, monkeypatch):
+        monkeypatch.setattr(kops, "on_tpu", lambda: False)
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+        assert kops.resolve_interpret() is True
+        assert kops.resolve_interpret(False) is False
+
+    @pytest.mark.parametrize("kw,why", [
+        (dict(stdp_chain=CHAIN_STDP), "plastic"),
+        (dict(method="rk4"), "euler"),
+    ])
+    def test_ineligible_net_reports_reason(self, kw, why):
+        net = _build("fp16", "fused", **kw)
+        assert not net.static.fused.kernel_ok
+        assert not net.static.fused_kernel
+        assert why in net.static.fused.kernel_reason
 
 
 class TestEnvInterpret:

@@ -1,0 +1,8 @@
+"""Device layer (XLA:TPU, Mosaic), sim cells: percent of the traced window in
+which no operation ran on the device (mean over the chips used)."""
+
+
+def read(ctx):
+    if ctx.kind != "sim" or ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)

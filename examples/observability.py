@@ -7,13 +7,21 @@ What the operator of a `repro.serve` deployment actually sees — the
    admission overflows rung 2, so the ladder migrates the whole fleet up
    mid-admission — ``rung_migrate`` span, ``export``/``restore`` per
    lane, rung-bytes gauges re-pointed, all recorded as it happens.
-2. Serve chunks and flush. Every chunk dispatch lands in the
-   ``repro_serve_chunk_latency_ms`` / ``repro_serve_us_per_tick``
-   histograms; jit dispatches are classified compile vs cache hit.
+2. Serve chunks, flushing every tenant after each — the serving loop.
+   Each ``pool.step`` is a ``step`` span (the host's work) around one
+   ``dispatch`` (the jit call, which returns before the device is done);
+   the first flush after it closes the ``chunk`` span once the chunk's
+   outputs are ready, which feeds the ``repro_serve_chunk_latency_ms`` /
+   ``repro_serve_us_per_tick`` histograms. Every ``flush`` holds one
+   ``read`` per device-to-host copy (``repro_flush_host_reads_total``);
+   compilations are filed under the span that ran them.
 3. Dump the observability record: a JSONL trace, a Chrome trace you can
-   open at https://ui.perfetto.dev, the Prometheus text snapshot, and
-   the health verdict against the paper's budgets (real-time factor on
-   the Cortex-M33 spec, per-rung bytes vs the 8.477 MB MCU ceiling).
+   open at https://ui.perfetto.dev, the Prometheus text snapshot, the
+   health verdict against the paper's budgets (real-time factor on
+   the Cortex-M33 spec, per-rung bytes vs the 8.477 MB MCU ceiling,
+   completed-chunk µs/tick vs the 1 ms tick), and one chunk under the
+   JAX profiler: the same spans, named ``repro.<span>``, on the profiler's
+   host plane beside the program launches and the device's operations.
 4. **Incident drill**: one tenant's fp16 membrane state is deliberately
    poisoned with a NaN. The network was compiled with
    ``watches="default"``, so the in-scan ``nonfinite`` watch counts the
@@ -27,12 +35,11 @@ What the operator of a `repro.serve` deployment actually sees — the
    ``tests/test_watch.py``).
 
 Observability is default-on and host-side only — device programs and
-results are bitwise identical with it off (``tests/test_obs.py``), the
-serving overhead is gated < 2% and the watch-enabled overhead < 5% in CI
-(``benchmarks/run.py --smoke``).
+results are bitwise identical with it off (``tests/test_obs.py``).
 
   PYTHONPATH=src python examples/observability.py
 """
+import glob
 import json
 import os
 import sys
@@ -74,13 +81,13 @@ def main() -> None:
               f"migrations so far {lad.migrations}")
 
     # Enough chunks that the one-off compile chunk falls outside the p95
-    # window of the measured-serve health check (it is host dispatch wall,
-    # merged across all chunks — including the first, compiling one).
+    # of the measured-serve health check (merged across all chunks —
+    # including the first, compiling one).
     for _ in range(24):
         pool.step(CHUNK)
-    for sid in pool.session_ids:
-        f = pool.flush(sid)
-        print(f"flush {sid}: {int(f['spike_count'].sum())} spikes "
+        flushed = {sid: pool.flush(sid) for sid in pool.session_ids}
+    for sid, f in flushed.items():
+        print(f"last flush {sid}: {int(f['spike_count'].sum())} spikes "
               f"over {f['n_ticks']} ticks")
 
     # -- the operator's view ------------------------------------------------
@@ -106,6 +113,14 @@ def main() -> None:
           f"compiles {n_compiles}, migrations {n_up} up")
     print(f"trace: {len(obs.tracer())} events "
           f"(dropped {obs.tracer().dropped}) -> {trace_jsonl}")
+    host_ms: dict[str, list[float]] = {}
+    for e in obs.tracer().snapshot():
+        if e.name in ("step", "dispatch", "chunk", "ready", "flush", "read"):
+            host_ms.setdefault(e.name, []).append(e.dur_us / 1e3)
+    for name, ms in host_ms.items():
+        print(f"  {name:8s} x{len(ms):3d}  median {np.median(ms):8.3f} ms")
+    reads = reg.counter("repro_flush_host_reads_total").value()
+    print(f"host reads by flushes: {int(reads)}")
     print(f"chrome trace (open in Perfetto): {trace_chrome}")
     print(f"prometheus snapshot: {prom_path}")
 
@@ -118,6 +133,23 @@ def main() -> None:
         print(f"  [{check['status']:4s}] {check['name']}: {check['detail']}")
     with open(os.path.join(OUT_DIR, "observability_health.json"), "w") as f:
         json.dump(health, f, indent=1)
+
+    # -- one timeline: the program's spans and the device's operations ------
+    profile_dir = os.path.join(OUT_DIR, "observability_profile")
+    with jax.profiler.trace(profile_dir):
+        pool.step(CHUNK)
+        for sid in pool.session_ids:
+            pool.flush(sid)
+    (xplane,) = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
+    counts: dict[str, int] = {}
+    for plane in jax.profiler.ProfileData.from_file(xplane).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    counts[e.name] = counts.get(e.name, 0) + 1
+    print(f"\nprofiler trace (TensorBoard/Perfetto): {xplane}")
+    print("  program spans on its host plane: "
+          + ", ".join(f"{k} x{v}" for k, v in sorted(counts.items())))
 
     # -- incident drill: NaN tenant -> trip -> quarantine -> replay ---------
     print("\n--- incident drill ---")

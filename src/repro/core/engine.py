@@ -739,19 +739,12 @@ class Engine:
         state = state if state is not None else self.net.state0
         if self.net.partition is not None:
             return self._run_partitioned(n_steps, state, **kw)
-        if not obs.enabled():
-            return run(self.net.static, self.net.params, state, n_steps,
-                       **kw)
         # Host-side span around the jit DISPATCH only — nothing inside the
         # traced computation changes, so results are bitwise identical
-        # with obs on/off (tests/test_obs.py). The cache probe before vs
-        # after the dispatch classifies it compile vs cache hit.
-        before = obs.jit_cache_size(run)
-        with obs.span("engine_run", n_ticks=n_steps,
-                      record=str(kw.get("record", "raster"))):
+        # with obs on/off (tests/test_obs.py).
+        with obs.span("dispatch", n_ticks=n_steps):
             out = run(self.net.static, self.net.params, state, n_steps,
                       **kw)
-        obs.note_dispatch("engine.run", run, before)
         obs.inc("repro_engine_ticks_total", float(n_steps))
         return out
 
@@ -797,15 +790,9 @@ class Engine:
                 "vmap over cores would replicate every core's tables per "
                 "trial; run trials through a ServePool instead")
         state = state if state is not None else self.net.state0
-        if not obs.enabled():
-            return run_batch(self.net.static, self.net.params, state,
-                             n_steps, batch, **kw)
-        before = obs.jit_cache_size(run_batch)
-        with obs.span("engine_run", n_ticks=n_steps, batch=batch,
-                      record=str(kw.get("record", "raster"))):
+        with obs.span("dispatch", n_ticks=n_steps, batch=batch):
             out = run_batch(self.net.static, self.net.params, state,
                             n_steps, batch, **kw)
-        obs.note_dispatch("engine.run_batch", run_batch, before)
         obs.inc("repro_engine_ticks_total", float(n_steps) * batch)
         return out
 

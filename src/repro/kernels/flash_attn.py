@@ -110,6 +110,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
             pltpu.VMEM((bq, dp), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_attn",
     )(qp, kp, vp)
     return out[:, :, :sq, :d]
 

@@ -364,6 +364,7 @@ def fused_tick(static, v, u, ring, gen_row, is_gen, a, b, c, d, t,
             jax.ShapeDtypeStruct(ring_p.shape, f32),
         ],
         interpret=interpret,
+        name="fused_tick",
     )(kp.meta, t.reshape(1).astype(jnp.int32),
       row(v), row(u), ring_p, row(gen_row, jnp.int32),
       row(is_gen, jnp.int32), row(a), row(b), row(c), row(d),

@@ -75,6 +75,7 @@ def izh4_update(v, u, i_syn, a, b, c, d, *, dt: float = 1.0, substeps: int = 2,
             jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         ],
         interpret=interpret,
+        name="izh_update",
     )(*args)
     return (vo.reshape(-1)[:n].astype(v.dtype),
             uo.reshape(-1)[:n].astype(u.dtype), sp.reshape(-1)[:n] != 0)

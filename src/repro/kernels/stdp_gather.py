@@ -98,6 +98,7 @@ def stdp_gather(w, idx, valid, pre_trace, post_trace, pre_spikes,
         out_specs=pl.BlockSpec((bq, fp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q + qp, fp), jnp.float32),
         interpret=interpret,
+        name="stdp_gather",
     )(wp, idxp, validp, pre_t, pre_s, post_t, post_s)
     return out[:q, :f].astype(w.dtype)
 

@@ -61,6 +61,7 @@ def stdp_update(w, mask, pre_trace, post_trace, pre_spikes, post_spikes, *,
         out_specs=pl.BlockSpec((bp, bq), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((p + pp, q + qp), jnp.float32),
         interpret=interpret,
+        name="stdp_update",
     )(wp, maskp, pre_t, post_t, pre_s, post_s)
     return out[:p, :q].astype(w.dtype)
 

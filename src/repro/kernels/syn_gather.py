@@ -99,6 +99,7 @@ def syn_gather(spikes, idx, w, *, block_q: int = DEFAULT_BLOCK_Q,
         out_specs=pl.BlockSpec((1, bq), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, q + qp), jnp.float32),
         interpret=interpret,
+        name="syn_gather",
     )(sp, idxp, wp)
     return out[0, :q]
 

@@ -64,6 +64,7 @@ def syn_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
         out_shape=jax.ShapeDtypeStruct((m + mp, n + np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="syn_matmul",
     )(xp, wp)
     return out[:m, :n].astype(out_dtype)
 

@@ -21,8 +21,9 @@ is live right now:
   says which rungs do. Sourced from the ledger when a network is given,
   else from the live ``repro_serve_rung_bytes`` gauges.
 * **Measured serve latency** (``serve_realtime_measured``): p95 of the
-  live ``repro_serve_us_per_tick`` histogram vs the 1000 µs/tick
-  real-time bar — present once any scheduler chunk has been recorded.
+  live ``repro_serve_us_per_tick`` histogram — completed chunks, step
+  entry to outputs ready (``obs.ChunkTimer``) — vs the 1000 µs/tick
+  real-time bar; present once a flush has closed a chunk.
 
 Status aggregates worst-of; the dict shape is JSON-safe and stable for
 artifacts (``benchmarks/run.py`` writes ``results/obs_health.json``).
@@ -126,8 +127,8 @@ def core_checks(core_bytes: dict[str, float], *,
 
 def measured_serve_check(registry, *, dt_ms: float = 1.0,
                          quantile: float = 0.95) -> HealthCheck | None:
-    """p-quantile of live serve µs/tick vs the real-time bar, merged
-    across rungs; None until a scheduler chunk has been recorded."""
+    """p-quantile of live completed-chunk µs/tick vs the real-time bar,
+    merged across rungs; None until a flush has closed a chunk."""
     hist = registry.get("repro_serve_us_per_tick")
     if hist is None or hist.kind != "histogram":
         return None
@@ -139,9 +140,9 @@ def measured_serve_check(registry, *, dt_ms: float = 1.0,
     return HealthCheck(
         name="serve_realtime_measured", status=status,
         value=round(p, 2), limit=limit,
-        detail=(f"p{int(quantile * 100)} serve dispatch "
+        detail=(f"p{int(quantile * 100)} completed serve chunk "
                 f"{p:.1f} us/tick vs {limit:.0f} us real-time bar "
-                "(host dispatch wall, all rungs merged)"))
+                "(step entry to outputs ready, all rungs merged)"))
 
 
 def watch_check(registry) -> HealthCheck | None:

@@ -14,6 +14,13 @@ two formats:
   ``"X"`` events for spans, ``"i"`` instants), loadable as-is in Perfetto
   (https://ui.perfetto.dev) or ``chrome://tracing``.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``: while a profiler trace is being collected
+(``jax.profiler.trace``), the span lands on the ``.xplane.pb`` host plane,
+on the same clock as the device's operations. The ``repro.`` prefix keeps
+program spans apart from any caller's own annotations. With no trace being
+collected, the annotation is skipped and its args are never formatted.
+
 Everything here is host-side Python: spans wrap jit *dispatch* calls and
 scheduler bookkeeping, never traced computation — which is why the
 runtime can guarantee bitwise-identical device results with tracing on or
@@ -32,17 +39,25 @@ import time
 from collections import deque
 from typing import Any, IO
 
-__all__ = ["EVENT_KINDS", "TraceEvent", "Tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["EVENT_KINDS", "PROFILER_PREFIX", "TraceEvent", "Tracer",
+           "annotate"]
+
+# Prefix of every span's name on the profiler trace.
+PROFILER_PREFIX = "repro."
 
 # The typed vocabulary the instrumented runtime emits (category "runtime").
 EVENT_KINDS = frozenset({
-    "compile",            # a jit dispatch added a cache entry
-    "jit_cache_hit",      # a jit dispatch reused a compiled program
+    "compile",            # an executable compiled or loaded from disk
     "admit",              # LaneScheduler.admit / ladder/pool admission
     "evict",              # LaneScheduler.evict (drains a final flush)
-    "step_chunk",         # one chunk dispatch (scheduler fleet or session)
-    "engine_run",         # one Engine.run / run_batch dispatch
+    "step",               # host work of one chunk (scheduler fleet or session)
+    "dispatch",           # the jit call of one chunk program / Engine.run
+    "chunk",              # step entry to the chunk's outputs being ready
+    "ready",              # a flush waiting for the chunk it closes
     "flush",              # telemetry drain to the host
+    "read",               # one device-to-host copy inside a flush
     "export",             # lane sliced out raw (migration payload)
     "restore",            # lane snapshot written back into a scheduler
     "rung_build",         # CapacityLadder built a rung's scheduler
@@ -81,6 +96,17 @@ def _cat(name: str) -> str:
     return "runtime" if name in EVENT_KINDS else "custom"
 
 
+def annotate(name: str, args: dict[str, Any]) -> TraceAnnotation | None:
+    """Enter the profiler-side twin of span ``name`` (``repro.<name>``,
+    ``args`` as its stats); None, at the cost of one check, when no
+    profiler trace is being collected. The caller exits it."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation(PROFILER_PREFIX + name, **args)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """Context manager recording one complete ("X") event on exit.
 
@@ -90,7 +116,8 @@ class _Span:
     ``args["error"]``.
     """
 
-    __slots__ = ("_tracer", "name", "args", "_t0_us", "depth", "dur_s")
+    __slots__ = ("_tracer", "name", "args", "_t0_us", "depth", "dur_s",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict[str, Any]):
         self._tracer = tracer
@@ -102,11 +129,14 @@ class _Span:
         stack = self._tracer._stack()
         self.depth = len(stack)
         stack.append(self.name)
-        self._t0_us = self._tracer._now_us()
+        self._ann = annotate(self.name, self.args)
+        self._t0_us = self._tracer.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        end_us = self._tracer._now_us()
+        end_us = self._tracer.now_us()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -140,15 +170,30 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, **args: Any) -> _Span:
-        """Context manager: ``with tracer.span("step_chunk", rung=...):``."""
+        """Context manager: ``with tracer.span("step", rung=...):``."""
         return _Span(self, name, args)
 
     def event(self, name: str, **args: Any) -> None:
         """Record an instant (``ph="i"``) event."""
         self._append(TraceEvent(
-            name=name, ph="i", ts_us=self._now_us(), dur_us=0.0,
+            name=name, ph="i", ts_us=self.now_us(), dur_us=0.0,
             tid=self._tid(), depth=len(self._stack()), cat=_cat(name),
             args=args))
+
+    def complete(self, name: str, t0_us: float, t1_us: float,
+                 **args: Any) -> None:
+        """Record a span whose start and end the caller took with
+        :meth:`now_us` — for an interval that no ``with`` block can
+        enclose, such as a chunk that completes inside a later call."""
+        self._append(TraceEvent(
+            name=name, ph="X", ts_us=t0_us, dur_us=t1_us - t0_us,
+            tid=self._tid(), depth=len(self._stack()), cat=_cat(name),
+            args=args))
+
+    def innermost(self) -> str | None:
+        """Name of the innermost span open on the calling thread."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     def _append(self, ev: TraceEvent) -> None:
         with self._lock:
@@ -233,7 +278,8 @@ class Tracer:
             json.dump(doc, path_or_file, default=str)
 
     # -- internals --------------------------------------------------------
-    def _now_us(self) -> float:
+    def now_us(self) -> float:
+        """Microseconds since the ring's epoch, the clock of ``ts_us``."""
         return (time.monotonic_ns() - self._epoch_ns) / 1e3
 
     def _tid(self) -> int:

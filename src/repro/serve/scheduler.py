@@ -70,7 +70,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import obs
 from repro.core.engine import _run_impl
 from repro.obs import watch as wat
-from repro.obs.metrics import us_per_tick
 from repro.core.network import CompiledNetwork, NetState
 from repro.precision.policy import tree_bytes
 from repro.telemetry import monitors as tel
@@ -223,6 +222,8 @@ class LaneScheduler:
         # the ledger key when namespaced (a ladder rung), else the bare
         # capacity — stable across the scheduler's lifetime.
         self._obs_rung = ledger_key or f"cap{capacity}"
+        # The last step's chunk, timed until a flush sees it ready.
+        self._chunks = obs.ChunkTimer(scope="scheduler", rung=self._obs_rung)
         for name in self._ledger_names:
             net.ledger.release(name)
         with net.ledger.stage("8. Serve Lanes"):
@@ -237,6 +238,7 @@ class LaneScheduler:
     def close(self) -> None:
         """Drop this scheduler's ledger registrations (a ladder migrating
         off a rung frees its lane bytes; the arrays die with the object)."""
+        self._chunks.drop()
         for name in self._ledger_names:
             self.net.ledger.release(name)
         for gauge in ("repro_serve_lane_occupancy",
@@ -464,38 +466,36 @@ class LaneScheduler:
         With a mesh, the lane axis is shard_map-partitioned across devices
         — zero collectives, bit-identical per lane to the unsharded step.
         """
+        if self._tel:  # only a flush can close the chunk
+            self._chunks.start(n_ticks)
         if not obs.enabled():
             return self._step_impl(n_ticks)
-        # Span wraps jit *dispatch*, not traced computation — the program
-        # and its outputs are bitwise identical with obs on or off.
+        # Spans wrap host work and jit *dispatch*, not traced computation —
+        # the program and its outputs are bitwise identical with obs on or
+        # off.
         occ = self.occupancy
-        fn = _step_lanes if self.mesh is None else _step_lanes_sharded
-        before = obs.jit_cache_size(fn)
-        with obs.span("step_chunk", rung=self._obs_rung, n_ticks=n_ticks,
-                      occupancy=occ) as sp:
+        with obs.span("step", rung=self._obs_rung, n_ticks=n_ticks,
+                      occupancy=occ):
             self._step_impl(n_ticks)
-        obs.note_dispatch("serve.step_lanes", fn, before)
-        obs.observe("repro_serve_chunk_latency_ms", sp.dur_s * 1e3,
-                    scope="scheduler", rung=self._obs_rung)
-        obs.observe("repro_serve_us_per_tick", us_per_tick(sp.dur_s, n_ticks),
-                    scope="scheduler", rung=self._obs_rung)
+        self._chunks.dispatched(self.states.t)
         obs.inc("repro_serve_ticks_total", float(n_ticks * occ),
                 rung=self._obs_rung)
 
     def _step_impl(self, n_ticks: int) -> None:
         tel_in = self._chunk_tel(n_ticks) if self._tel else None
         watch_in = self._watch if self._watch else None
-        if self.mesh is None:
-            out = _step_lanes(self.static, self.net.params, self.states,
-                              self.gen_keys, self.active, n_ticks,
-                              self.record, tel_carry=tel_in,
-                              watch_carry=watch_in)
-        else:
-            out = _step_lanes_sharded(self.static, self.net.params,
-                                      self.states, self.gen_keys,
-                                      self.active, n_ticks, self.record,
-                                      self.mesh, self.mesh_axis,
-                                      tel_carry=tel_in, watch_carry=watch_in)
+        with obs.span("dispatch", n_ticks=n_ticks):
+            if self.mesh is None:
+                out = _step_lanes(self.static, self.net.params, self.states,
+                                  self.gen_keys, self.active, n_ticks,
+                                  self.record, tel_carry=tel_in,
+                                  watch_carry=watch_in)
+            else:
+                out = _step_lanes_sharded(
+                    self.static, self.net.params, self.states,
+                    self.gen_keys, self.active, n_ticks, self.record,
+                    self.mesh, self.mesh_axis, tel_carry=tel_in,
+                    watch_carry=watch_in)
         self.states, *rest = out
         if self._tel:
             self._tel = rest[0]
@@ -552,6 +552,7 @@ class LaneScheduler:
             raise ValueError("scheduler built with record='none'")
         lane = self.lane_of(session_id)
         with obs.span("flush", rung=self._obs_rung, session=session_id):
+            self._chunks.close()
             values, zeroed = tel.flush_carry(self.net.static,
                                              _read_lane(self._tel, lane))
             self._tel = _write_lane(self._tel, lane, zeroed)

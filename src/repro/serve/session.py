@@ -37,7 +37,6 @@ from repro import obs
 from repro.core.engine import Engine
 from repro.core.network import CompiledNetwork, NetState
 from repro.obs import watch as wat
-from repro.obs.metrics import us_per_tick
 from repro.telemetry import monitors as tel
 
 __all__ = ["Session", "SessionMonitors"]
@@ -62,6 +61,8 @@ class SessionMonitors:
         self.static = static
         self.carry: tuple | None = None  # None until the first chunk runs
         self.ticks_since_flush = 0
+        # The session's last chunk, timed until this flush sees it ready.
+        self.chunks = obs.ChunkTimer(scope="session", rung="solo")
 
     def chunk_carry(self, n_ticks: int) -> tuple:
         """The ``tel_carry`` to feed the next ``run`` call of ``n_ticks``."""
@@ -91,6 +92,7 @@ class SessionMonitors:
         if self.carry is None:
             raise RuntimeError("flush() before any chunk has run")
         with obs.span("flush", scope="session"):
+            self.chunks.close()
             values, self.carry = tel.flush_carry(self.static, self.carry)
             values["n_ticks"] = self.ticks_since_flush
             self.ticks_since_flush = 0
@@ -168,32 +170,30 @@ class Session:
         chunk's raster (the parity/debug mode); ``"none"`` runs bare.
         """
         want_mon = record in ("monitors", "both")
-        if want_mon:
-            if self.monitors is None:
-                raise ValueError(
-                    "session created with monitors=False (or a monitor-free "
-                    "network) cannot record='monitors'")
-            kw["tel_carry"] = self.monitors.chunk_carry(n_ticks)
-            kw["return_tel_carry"] = True
-        want_watch = bool(self.engine.net.static.watches)
-        if want_watch and self.watch_carry is not None:
-            kw["watch_carry"] = self.watch_carry
-        with obs.span("step_chunk", scope="session", n_ticks=n_ticks,
-                      record=record) as sp:
+        if want_mon and self.monitors is None:
+            raise ValueError(
+                "session created with monitors=False (or a monitor-free "
+                "network) cannot record='monitors'")
+        if self.monitors is not None:
+            self.monitors.chunks.start(n_ticks)
+        with obs.span("step", scope="session", n_ticks=n_ticks,
+                      record=record):
+            if want_mon:
+                kw["tel_carry"] = self.monitors.chunk_carry(n_ticks)
+                kw["return_tel_carry"] = True
+            want_watch = bool(self.engine.net.static.watches)
+            if want_watch and self.watch_carry is not None:
+                kw["watch_carry"] = self.watch_carry
             self.state, out = self.engine.run(
                 n_ticks, state=self.state, record=record,
                 gen_base=self.gen_key, **kw)
-        if sp is not None:
-            obs.observe("repro_serve_chunk_latency_ms", sp.dur_s * 1e3,
-                        scope="session", rung="solo")
-            obs.observe("repro_serve_us_per_tick",
-                        us_per_tick(sp.dur_s, n_ticks),
-                        scope="session", rung="solo")
-        if want_mon:
-            self.monitors.absorb(out.pop("tel_carry"), n_ticks)
-        if want_watch:
-            self.watch_carry = out.pop("watch_carry")
-        self.ticks += n_ticks
+            if want_mon:
+                self.monitors.absorb(out.pop("tel_carry"), n_ticks)
+            if want_watch:
+                self.watch_carry = out.pop("watch_carry")
+            self.ticks += n_ticks
+        if self.monitors is not None:
+            self.monitors.chunks.dispatched(self.state.t)
         return out
 
     def check_watches(self) -> list:

@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "SpikeCount",
     "GroupRate",
@@ -241,24 +243,34 @@ def flush_carry(static, carry: tuple) -> tuple[dict, tuple]:
       invariance).
 
     Cost is O(N) per flush, independent of how many ticks elapsed — the
-    periodic host sync of an unbounded serving session.
+    periodic host sync of an unbounded serving session. Each monitor's
+    per-group values cross to the host in one copy (a ``read`` span,
+    counted by ``repro_flush_host_reads_total``).
     """
     out: dict = {}
     new = []
     for s, c in zip(static.monitors, carry):
         if isinstance(s, SpikeCount):
-            out[s.name] = np.asarray(jnp.stack([
+            out[s.name] = _read(jnp.stack([
                 c[g.start:g.start + g.size].sum() for g in static.groups
             ]))
             new.append(jnp.zeros_like(c))
         elif isinstance(s, GroupRate):
-            out[s.name] = np.asarray(jnp.stack([
+            out[s.name] = _read(jnp.stack([
                 c[g.start:g.start + g.size].mean() for g in static.groups
             ]))
             new.append(c)  # filter level persists — see docstring
         else:
             new.append(c)
     return out, tuple(new)
+
+
+def _read(x: jax.Array) -> np.ndarray:
+    """One device-to-host copy of a flush."""
+    with obs.span("read"):
+        host = np.asarray(x)
+    obs.inc("repro_flush_host_reads_total")
+    return host
 
 
 def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,

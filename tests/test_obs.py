@@ -13,6 +13,7 @@ paper's budgets, and the typed checkpoint-failure surface.
 """
 import json
 import os
+import time
 
 import jax
 import numpy as np
@@ -55,11 +56,11 @@ class TestTracer:
     def test_nested_spans_record_depth_and_duration(self):
         tr = Tracer()
         with tr.span("admit", rung="cap4"):
-            with tr.span("step_chunk", n_ticks=10):
+            with tr.span("step", n_ticks=10):
                 pass
         inner, outer = tr.snapshot()  # inner exits (and records) first
         assert (outer.name, outer.depth) == ("admit", 0)
-        assert (inner.name, inner.depth) == ("step_chunk", 1)
+        assert (inner.name, inner.depth) == ("step", 1)
         assert outer.dur_us >= inner.dur_us >= 0.0
         assert outer.cat == inner.cat == "runtime"
         assert outer.args == {"rung": "cap4"}
@@ -90,14 +91,14 @@ class TestTracer:
     def test_jsonl_export(self, tmp_path):
         tr = Tracer(capacity=8)
         tr.event("admit", session="a")
-        with tr.span("step_chunk"):
+        with tr.span("step"):
             pass
         path = tmp_path / "trace.jsonl"
         tr.to_jsonl(str(path))
         lines = [json.loads(line) for line in path.read_text().splitlines()]
         assert lines[0]["meta"]["retained"] == 2
         assert lines[0]["meta"]["capacity"] == 8
-        assert [ln["name"] for ln in lines[1:]] == ["admit", "step_chunk"]
+        assert [ln["name"] for ln in lines[1:]] == ["admit", "step"]
         assert lines[1]["ph"] == "i" and lines[2]["ph"] == "X"
 
     def test_chrome_export_is_loadable_trace_json(self, tmp_path):
@@ -138,8 +139,8 @@ class TestMetrics:
         h = Histogram("h", buckets=(1.0, 10.0))
         h.observe(0.5, rung="a")
         h.observe(9.0, rung="b")
-        assert h.quantile(1.0, {"rung": "a"}) == pytest.approx(1.0)
-        assert h.quantile(1.0) == pytest.approx(10.0)  # labels=None merges
+        assert h.quantile(1.0, {"rung": "a"}) == pytest.approx(0.5)
+        assert h.quantile(1.0) == pytest.approx(9.0)  # labels=None merges
 
     def test_prometheus_cumulative_buckets_and_headers(self):
         reg = MetricsRegistry()
@@ -195,7 +196,7 @@ class TestMetrics:
 class TestFacade:
     def test_disabled_records_nothing(self):
         obs.configure(enabled=False)
-        with obs.span("step_chunk") as sp:
+        with obs.span("step") as sp:
             assert sp is None
         obs.event("admit")
         obs.inc("repro_serve_admits_total")
@@ -208,15 +209,55 @@ class TestFacade:
         jax.clear_caches()
         eng = Engine(_mini())
         eng.run(17)  # unusual static tick count -> fresh compile
-        eng.run(17)  # same entry -> cache hit
         reg = obs.registry()
+        compiles = reg.counter("repro_compiles_total").value(site="dispatch")
+        assert compiles >= 1
+        eng.run(17)  # same entry -> served from the jit cache
         assert reg.counter("repro_compiles_total").value(
-            site="engine.run") >= 1
-        assert reg.counter("repro_jit_cache_hits_total").value(
-            site="engine.run") >= 1
-        names = [e.name for e in obs.tracer().snapshot()]
-        assert "compile" in names and "jit_cache_hit" in names
+            site="dispatch") == compiles
+        events = [e for e in obs.tracer().snapshot() if e.name == "compile"]
+        assert sum(e.args["site"] == "dispatch" for e in events) == compiles
+        assert all(e.args["secs"] > 0 for e in events)
+        assert reg.get("repro_jit_cache_hits_total") is None
         assert reg.counter("repro_engine_ticks_total").value() == 34.0
+
+    def test_forced_compile_is_filed_under_the_enclosing_span(self):
+        x = np.arange(5, dtype=np.float32)
+        with obs.span("dispatch"):
+            with obs.span("custom_site"):
+                jax.jit(lambda v: v * 3.0 - 1.0)(x).block_until_ready()
+        (ev,) = [e for e in obs.tracer().snapshot() if e.name == "compile"]
+        assert ev.args["site"] == "custom_site"
+        assert obs.registry().counter("repro_compiles_total").value(
+            site="custom_site") == 1.0
+        obs.configure(enabled=False)
+        jax.jit(lambda v: v * 5.0)(x).block_until_ready()
+        assert obs.registry().counter("repro_compiles_total").value(
+            site="none") == 0.0
+
+    def test_span_lands_on_the_profiler_host_plane(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("flush", rung="cap4"):
+                with obs.span("read"):
+                    jax.numpy.ones(8).block_until_ready()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        host = [e for p in ProfileData.from_file(str(path)).planes
+                if p.name.startswith("/host:")
+                for line in p.lines for e in line.events]
+        by_name = {e.name: e for e in host}
+        assert {"repro.flush", "repro.read"} <= set(by_name)
+        assert dict(by_name["repro.flush"].stats)["rung"] == "cap4"
+        flush, read = by_name["repro.flush"], by_name["repro.read"]
+        assert flush.start_ns <= read.start_ns
+        assert (read.start_ns + read.duration_ns
+                <= flush.start_ns + flush.duration_ns)
+        # Program spans never take a bare name a caller's annotation uses.
+        assert "flush" not in by_name and "read" not in by_name
+        # The ring recorded both spans as well.
+        assert [e.name for e in obs.tracer().snapshot()
+                if e.ph == "X"] == ["read", "flush"]
 
     def test_env_var_default(self, monkeypatch):
         from repro.obs import _env_enabled
@@ -313,7 +354,7 @@ class TestServeInstrumentation:
         h = reg.histogram("repro_serve_us_per_tick")
         assert h.count(scope="scheduler", rung="cap4") == 1
         names = [e.name for e in obs.tracer().snapshot()]
-        for expected in ("admit", "step_chunk", "evict"):
+        for expected in ("admit", "step", "evict"):
             assert expected in names
         sched.close()
         # close() drops the rung's occupancy/capacity gauge series
@@ -351,8 +392,80 @@ class TestServeInstrumentation:
         sess = Session.create(_mini(), seed=3)
         sess.run(40)
         h = obs.registry().histogram("repro_serve_chunk_latency_ms")
+        assert h.count(scope="session", rung="solo") == 0  # not yet flushed
+        sess.flush()
         assert h.count(scope="session", rung="solo") == 1
+        sess.run(40)
+        sess.run(40)  # the unflushed chunk before it goes untimed
+        sess.flush()
+        assert h.count(scope="session", rung="solo") == 2
 
+    def test_chunk_ends_once_outputs_are_ready(self):
+        net = _mini()
+        sched = LaneScheduler(net, 2)
+        sched.admit("a", seed=1)
+        sched.step(40)  # compiles: its chunk would pass every bucket edge
+        sched.flush("a")
+        obs.configure(reset=True)
+        sched.step(40)
+        jax.block_until_ready(sched.states)
+        ready_us = obs.tracer().now_us()
+        sched.flush("a")
+        sched.flush("a")  # the chunk is closed once
+        events = obs.tracer().snapshot()
+        (chunk,) = [e for e in events if e.name == "chunk"]
+        (step,) = [e for e in events if e.name == "step"]
+        (dispatch,) = [e for e in events if e.name == "dispatch"]
+        assert chunk.args == {"n_ticks": 40, "scope": "scheduler",
+                              "rung": "cap2"}
+        assert chunk.ts_us <= step.ts_us <= dispatch.ts_us
+        assert chunk.ts_us + chunk.dur_us >= ready_us
+        assert "ready" not in [e.name for e in events]  # it was ready
+        h = obs.registry().histogram("repro_serve_us_per_tick")
+        assert h.count(scope="scheduler", rung="cap2") == 1
+        check = obs.health.measured_serve_check(obs.registry())
+        assert check.value == pytest.approx(chunk.dur_us / 40, rel=1e-3)
+        assert "completed serve chunk" in check.detail
+        sched.close()
+
+    def test_chunk_waits_inside_ready_when_outputs_are_late(self):
+        class Late:  # an output the device finishes 20 ms after the flush
+            ready_us = None
+
+            def is_ready(self):
+                return False
+
+            def block_until_ready(self):
+                time.sleep(0.02)
+                Late.ready_us = obs.tracer().now_us()
+                return self
+
+        timer = obs.ChunkTimer(scope="session", rung="solo")
+        timer.start(10)
+        timer.dispatched(Late())
+        with obs.span("flush"):
+            timer.close()
+        events = {e.name: e for e in obs.tracer().snapshot()}
+        chunk, ready = events["chunk"], events["ready"]
+        assert chunk.ts_us + chunk.dur_us >= Late.ready_us
+        assert ready.dur_us >= 20_000 * 0.9
+        assert ready.ts_us >= chunk.ts_us
+        assert obs.registry().histogram("repro_serve_us_per_tick").count(
+            scope="session", rung="solo") == 1
+
+    def test_flush_counts_two_host_reads_with_default_monitors(self):
+        sess = Session.create(_mini(), seed=4)
+        for _ in range(3):
+            sess.run(20)
+            sess.flush()
+        reads = obs.registry().counter("repro_flush_host_reads_total")
+        assert reads.value() == 6.0
+        events = obs.tracer().snapshot()
+        flushes = [e for e in events if e.name == "flush"]
+        inside = [e for e in events if e.name == "read"
+                  and any(f.ts_us <= e.ts_us and e.ts_us + e.dur_us
+                          <= f.ts_us + f.dur_us for f in flushes)]
+        assert len(flushes) == 3 and len(inside) == 6
 
 # ---------------------------------------------------------------------------
 # health snapshots vs the paper's budgets

@@ -45,10 +45,13 @@ class TestQuantileEdges:
 
     def test_single_sample_interpolates_within_landing_bucket(self):
         h = Histogram("h", buckets=EDGES)
-        h.observe(3.0)  # lands in (1, 5]
-        assert h.quantile(0.0) == pytest.approx(1.0)
-        assert h.quantile(0.5) == pytest.approx(3.0)  # 1 + (5-1)*0.5
-        assert h.quantile(1.0) == pytest.approx(5.0)
+        h.observe(3.0)  # lands in (1, 5]; the bucket has seen only 3.0
+        assert h.quantile(0.0) == pytest.approx(3.0)
+        assert h.quantile(0.5) == pytest.approx(3.0)
+        assert h.quantile(1.0) == pytest.approx(3.0)
+        h.observe(4.0)  # interpolation spans what the bucket has seen
+        assert h.quantile(0.5) == pytest.approx(3.5)  # 3 + (4-3)*1/2
+        assert h.quantile(1.0) == pytest.approx(4.0)
 
     def test_single_sample_first_bucket_interpolates_from_zero(self):
         h = Histogram("h", buckets=EDGES)
@@ -176,7 +179,7 @@ class TestTracerThreadSafety:
     def _hammer(self, tracer, barrier, tids_seen, idx):
         barrier.wait()
         for i in range(self.PER_THREAD):
-            with tracer.span("step_chunk", thread=idx, i=i):
+            with tracer.span("step", thread=idx, i=i):
                 tracer.event("flush", thread=idx, i=i)
         # tid must be stable across calls within one thread
         tids_seen[idx] = {tracer._tid() for _ in range(4)}

@@ -14,7 +14,8 @@ One chip:
   ``backend="xla"`` bit for bit, fp16-vs-fp32 spike-count accuracy must
   reach the paper's 97.5%, and the spike count must sit in the Synfire4
   band. Synfire4×10 (12k neurons) with ``propagation="sparse"`` gets the
-  same bitwise check.
+  same bitwise check; its line gives the CSR gather's chunk passes per
+  tick (``csr_chunks``: each tile's source window against the whole row).
 * serve — 64 Synfire4 tenants in one ``LaneScheduler``, five chunks of
   100 ticks with a flush after each; every flush must equal a solo
   ``Session`` of the same seed bit for bit.
@@ -108,6 +109,7 @@ def _timed_run(net, ticks: int):
 def engine_pair(cfg, policy: str, propagation: str, ticks: int) -> int:
     """fused (megakernel engaged) vs xla on the same chip; returns the
     spike count. Raises on any failed check."""
+    from repro import obs
     from repro.configs.synfire4 import build_synfire
 
     runs = {}
@@ -118,12 +120,16 @@ def engine_pair(cfg, policy: str, propagation: str, ticks: int) -> int:
         final, raster, wall, comp, repeat_ok = _timed_run(net, ticks)
         runs[backend] = (net, final, raster)
         fused = net.static.fused
+        # the megakernel payload assembled for this run publishes them
+        chunks = obs.registry().get("repro_fused_csr_chunks")
+        csr_chunks = ({w: chunks.value(walk=w) for w in ("window", "row")}
+                      if net.static.fused_kernel and chunks else None)
         log("engine", net=cfg.name, policy=policy, propagation=propagation,
             backend=backend, ticks=ticks, compile_s=round(comp, 3),
             wall_s=round(wall, 4), spikes=int(raster.sum()),
             fused_kernel=net.static.fused_kernel,
             kernel_reason=fused.kernel_reason if fused else None,
-            repeat_bitwise=repeat_ok)
+            csr_chunks=csr_chunks, repeat_bitwise=repeat_ok)
         if not repeat_ok:
             raise AssertionError(f"{cfg.name}/{policy}/{backend}: two runs "
                                  "from state0 differ")

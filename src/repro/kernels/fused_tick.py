@@ -17,12 +17,16 @@ the ring is ``[L, Np]``.  Dense bucket images are stacked into one
 buckets concatenate their fan-in rows into ``[R, Fp]`` index/weight tables
 streamed in ``(tile_r, Fp)`` row tiles — the in-kernel ``lane_take``
 subsumes the standalone ``syn_gather`` lowering.  A scalar-prefetch schedule
-(``meta[i] = (kind, sel, pre_start, post_off, kpos, qt)``) drives both the
-BlockSpec index maps (which weight tile to DMA for grid step ``i``) and
-the in-kernel placement of each tile's drive.  Every window offset in the
-schedule is a multiple of 128: ``assemble_kernel`` shifts each bucket's
-image (or CSR rows) inside its tile by the bucket's offset from the lane
-boundary, so the kernel only ever slices lane-aligned windows.
+(``meta[i] = (kind, sel, pre_start, post_off, kpos, qt, n_chunks)``) drives
+both the BlockSpec index maps (which weight tile to DMA for grid step
+``i``) and the in-kernel placement of each tile's drive.  A CSR row tile
+belongs to one bucket, whose sources are one contiguous span: its
+``pre_start``/``n_chunks`` name the 128-lane chunks that span covers, and
+the gather walks only those (a chunk outside it holds no live index).
+Every window offset in the schedule is a multiple of 128:
+``assemble_kernel`` shifts each bucket's image (or CSR rows) inside its
+tile by the bucket's offset from the lane boundary, so the kernel only
+ever slices lane-aligned windows.
 
 Grid step 0 runs the tick prologue (ring read → ``i_syn``, slot zeroing,
 IZH4 update, generator overrides, spike vector, accumulator clear); every
@@ -66,13 +70,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels.syn_gather import lane_take
 
 LANE = 128
 _MAX_TILE_R = 512
 
 # meta column indices (schedule rows, scalar-prefetched to SMEM)
-_KIND, _SEL, _PRE, _POST, _KPOS, _QT = range(6)
+_KIND, _SEL, _PRE, _POST, _KPOS, _QT, _NCH = range(7)
 
 
 class KernelPayload(NamedTuple):
@@ -82,7 +87,7 @@ class KernelPayload(NamedTuple):
     members are closed over by the scan body, the ints parameterize the
     kernel trace."""
 
-    meta: jax.Array  # [n_steps, 6] int32 tile schedule (scalar prefetch)
+    meta: jax.Array  # [n_steps, 7] int32 tile schedule (scalar prefetch)
     w_stack: jax.Array  # [Bd, Pp, Qp] f32 stacked dense bucket images
     csr_idx: jax.Array  # [R, Fp] int32 global fan-in ids (pad -> 0)
     csr_w: jax.Array  # [R, Fp] f32 fan-in weights (pad -> +0.0)
@@ -92,6 +97,8 @@ class KernelPayload(NamedTuple):
     tile_q: int
     tile_r: int
     f_pad: int
+    csr_chunks: int  # 128-lane chunks the CSR gather walks per tick
+    csr_row_chunks: int  # ... were every tile to walk the whole spike row
 
 
 def _ceil_to(x: int, mult: int) -> int:
@@ -113,7 +120,15 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
     rows to the ``tile_r`` grid, and the tile schedule is laid out as one
     int32 row per grid step.  Each image sits at its bucket's offset from
     the lane boundary (pre rows and post columns alike; CSR rows likewise)
-    so that every window the schedule names starts on a lane boundary."""
+    so that every window the schedule names starts on a lane boundary.
+
+    A CSR row tile's gather window is its bucket's source span, rounded out
+    to whole 128-lane chunks.  Pad cells (``idx 0``, weight ``+0.0``) may
+    fall outside it and gather ``0.0`` instead of spike 0 — the same
+    ``+0.0`` product.  Whenever the tables are concrete (an eager call, not
+    a trace) every index with a non-zero weight is checked to lie inside
+    its tile's window; the chunk counts go to the
+    ``repro_fused_csr_chunks`` gauge."""
     plan = static.fused
     buckets = static.buckets
     dense_ids = [bi for bi, b in enumerate(buckets) if b.kind == "dense"]
@@ -142,17 +157,21 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
             default=1), LANE)
     tile_r = max(LANE, min(plan.tile_r // LANE * LANE, _MAX_TILE_R))
     row_blocks: list[tuple[jax.Array, jax.Array]] = []
-    csr_meta: list[tuple[int, int]] = []  # (post_off, kpos) per row tile
+    # (post_off, kpos, pre_base, n_chunks) per row tile
+    csr_meta: list[tuple[int, int, int, int]] = []
     for bi in sparse_ids:
         b = buckets[bi]
         base, sh = _lane_split(b.post_start)
+        pre_base, pre_sh = _lane_split(b.pre_start)
+        n_win = -(-(pre_sh + b.p) // LANE)
         idx = params.bucket_csr_idx[bi].astype(jnp.int32) + b.pre_start
         w = packed[bi]
         rows = _ceil_to(sh + b.q, tile_r)
         pad = ((sh, rows - sh - b.q), (0, f_pad - idx.shape[1]))
         row_blocks.append((jnp.pad(idx, pad), jnp.pad(w, pad)))
         for rt in range(rows // tile_r):
-            csr_meta.append((base + rt * tile_r, kpos[b.delay_ms]))
+            csr_meta.append((base + rt * tile_r, kpos[b.delay_ms],
+                             pre_base, n_win))
     if row_blocks:
         csr_idx = jnp.concatenate([ib for ib, _ in row_blocks])
         csr_w = jnp.concatenate([wb for _, wb in row_blocks])
@@ -168,20 +187,47 @@ def assemble_kernel(static, params, packed) -> KernelPayload:
         post_base = _lane_split(b.post_start)[0]
         for qt in range(n_qt):
             meta.append([0, pos, pre_base, post_base + qt * tile_q,
-                         kpos[b.delay_ms], qt])
-    for rt, (post_off, k) in enumerate(csr_meta):
-        meta.append([1, rt, 0, post_off, k, 0])
+                         kpos[b.delay_ms], qt, 0])
+    for rt, (post_off, k, pre_base, n_win) in enumerate(csr_meta):
+        meta.append([1, rt, pre_base, post_off, k, 0, n_win])
     if not meta:  # projection-free net: one no-op step (prologue+epilogue)
-        meta.append([-1, 0, 0, 0, 0, 0])
+        meta.append([-1, 0, 0, 0, 0, 0, 0])
+    meta_np = np.asarray(meta, np.int32)
 
     slack = max(p_pad, q_pad, tile_r, LANE)
     n_pad = _ceil_to(static.n + slack, LANE)
+    if not isinstance(csr_idx, jax.core.Tracer):
+        _check_windows(meta_np, np.asarray(csr_idx), np.asarray(csr_w),
+                       tile_r)
+    csr_chunks = sum(n_win for *_, n_win in csr_meta)
+    csr_row_chunks = len(csr_meta) * (n_pad // LANE)
+    obs.gauge("repro_fused_csr_chunks", float(csr_chunks), walk="window")
+    obs.gauge("repro_fused_csr_chunks", float(csr_row_chunks), walk="row")
     return KernelPayload(
-        meta=jnp.asarray(np.asarray(meta, np.int32)),
+        meta=jnp.asarray(meta_np),
         w_stack=w_stack, csr_idx=csr_idx, csr_w=csr_w,
         n_steps=len(meta), n_pad=n_pad, p_pad=p_pad,
         tile_q=tile_q, tile_r=tile_r, f_pad=f_pad,
+        csr_chunks=csr_chunks, csr_row_chunks=csr_row_chunks,
     )
+
+
+def _check_windows(meta: np.ndarray, idx: np.ndarray, w: np.ndarray,
+                   tile_r: int) -> None:
+    """Raise unless every CSR cell with a non-zero weight indexes a spike
+    inside its row tile's gather window (schedule rows of kind 1, in tile
+    order)."""
+    tiles = meta[meta[:, _KIND] == 1]
+    lo = np.repeat(tiles[:, _PRE], tile_r)[:, None]
+    hi = lo + LANE * np.repeat(tiles[:, _NCH], tile_r)[:, None]
+    n = len(lo)
+    outside = (w[:n] != 0) & ((idx[:n] < lo) | (idx[:n] >= hi))
+    if outside.any():
+        r, f = np.argwhere(outside)[0]
+        raise ValueError(
+            f"CSR row {r}, fan-in slot {f}: index {idx[r, f]} with weight "
+            f"{w[r, f]} lies outside its tile's gather window "
+            f"[{lo[r, 0]}, {hi[r, 0]})")
 
 
 def _bits(x, dtype):
@@ -282,7 +328,9 @@ def _tick_kernel(m_ref, t_ref, v_ref, u_ref, ring_ref, gen_ref, isg_ref,
     def _csr_tile():
         po = pl.multiple_of(m_ref[i, _POST], LANE)
         k = m_ref[i, _KPOS]
-        g = lane_take(so_ref, ci_ref[...])  # in-kernel fan-in gather
+        # in-kernel fan-in gather over the bucket's source chunks only
+        g = lane_take(so_ref, ci_ref[...], base=m_ref[i, _PRE],
+                      n_chunks=m_ref[i, _NCH])
         drive = (g * cw_ref[...]).sum(axis=1)  # [tile_r]
         acc_ref[k, :, pl.ds(po, tile_r)] += drive[None]
 

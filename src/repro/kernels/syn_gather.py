@@ -31,7 +31,7 @@ LANE = 128
 DEFAULT_BLOCK_Q = 256  # post neurons per grid step (a multiple of LANE)
 
 
-def lane_take(row_ref, idx: jax.Array) -> jax.Array:
+def lane_take(row_ref, idx: jax.Array, base=0, n_chunks=None) -> jax.Array:
     """``g[r, f] = row_ref[0, idx[r, f]]`` as f32, for a VMEM-resident
     ``[1, Np]`` row (``Np`` a multiple of 128) and a ``[R, F]`` int32 index
     tile (``F`` a multiple of 128).
@@ -41,15 +41,22 @@ def lane_take(row_ref, idx: jax.Array) -> jax.Array:
     tile's rows and gathered with ``take_along_axis`` per 128-column block,
     and the lanes whose index falls in the chunk keep the value. Every
     output cell is selected from exactly one chunk — no arithmetic touches
-    it, so the result is bitwise a plain ``take``."""
+    it, so the result is bitwise a plain ``take``.
+
+    ``base`` (a multiple of 128) and ``n_chunks`` restrict the walk to the
+    window ``[base, base + 128 * n_chunks)``; either may be a traced scalar
+    (e.g. read from SMEM). Indices outside the window read ``0.0``. The
+    default walks the whole row."""
     rows, f = idx.shape
-    n_chunks = row_ref.shape[1] // LANE
+    c0 = base // LANE
+    if n_chunks is None:
+        n_chunks = row_ref.shape[1] // LANE - c0
 
     def chunk(c, g):
-        base = pl.multiple_of(c * LANE, LANE)
+        off = pl.multiple_of(c * LANE, LANE)
         src = jnp.broadcast_to(
-            row_ref[:, pl.ds(base, LANE)].astype(jnp.float32), (rows, LANE))
-        loc = idx - base
+            row_ref[:, pl.ds(off, LANE)].astype(jnp.float32), (rows, LANE))
+        loc = idx - off
         hit = (loc >= 0) & (loc < LANE)
         loc = jnp.where(hit, loc, 0)
         got = jnp.concatenate(
@@ -57,7 +64,7 @@ def lane_take(row_ref, idx: jax.Array) -> jax.Array:
              for j in range(0, f, LANE)], axis=1)
         return jnp.where(hit, got, g)
 
-    return jax.lax.fori_loop(0, n_chunks, chunk,
+    return jax.lax.fori_loop(c0, c0 + n_chunks, chunk,
                              jnp.zeros((rows, f), jnp.float32))
 
 

@@ -128,6 +128,10 @@ DECLARED: dict[str, tuple[str, str, tuple | None]] = {
         "(MemoryLedger.serve_rung_bytes)", None),
     "repro_bench_us_per_tick": (
         "gauge", "Best-of-N bench-cell microseconds per tick", None),
+    "repro_fused_csr_chunks": (
+        "gauge", "128-lane chunks the megakernel's CSR gather walks per "
+        "tick, over each row tile's source window (walk=window) or the "
+        "whole spike row (walk=row), for the last payload assembled", None),
 }
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")

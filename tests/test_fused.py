@@ -23,6 +23,7 @@ from repro.configs.synfire4 import (
     build_synfire,
 )
 from repro.core import Engine
+from repro.core import neurons as nrn
 from repro.core.plasticity import HomeostasisConfig
 from repro.kernels import ops as kops
 from repro.kernels.ops import env_interpret
@@ -115,8 +116,8 @@ class TestFusedKernel:
     """The Pallas megakernel tick (``NetStatic.fused_kernel``), forced on
     via the compile-time flag (interpret execution on CPU)."""
 
-    def _kernel_net(self, policy, prop):
-        net = _build(policy, "fused", prop)
+    def _kernel_net(self, policy, prop, cfg=SYNFIRE4_MINI):
+        net = _build(policy, "fused", prop, cfg=cfg)
         assert net.static.fused.kernel_ok
         static = dataclasses.replace(net.static, fused_kernel=True)
         return dataclasses.replace(net, static=static)
@@ -132,6 +133,38 @@ class TestFusedKernel:
             f"{int(np.argwhere((rx != rf).any(axis=1))[0][0])}"
         )
         _assert_state_equal(fx, ff)
+
+    @pytest.mark.parametrize("policy", ["fp32", "fp16"])
+    def test_kernel_windowed_csr_full_synfire4_matches_xla(self, policy):
+        """Full Synfire4 in CSR rows (1,200 neurons, 14-chunk spike row):
+        the CSR tiles gather over their buckets' source windows only, and
+        raster and final state stay bitwise equal to the XLA path."""
+        ticks = 100
+        net_x = _build(policy, "xla", "sparse", cfg=SYNFIRE4)
+        fx, rx = _run(net_x, ticks)
+        ff, rf = _run(self._kernel_net(policy, "sparse", cfg=SYNFIRE4),
+                      ticks)
+        gen = np.asarray(net_x.params.neuron.model
+                         == nrn.NeuronModel.GENERATOR)
+        assert rx[:, ~gen].sum() > 1000, "no wave past the drive"
+        assert np.array_equal(rx, rf)
+        _assert_state_equal(fx, ff)
+
+    def test_csr_chunks_gauge(self):
+        """Assembling a sparse megakernel payload publishes its gather's
+        chunk passes per tick, windowed and whole-row."""
+        from repro import obs
+        from repro.core import backend as be
+
+        net = self._kernel_net("fp16", "sparse", cfg=SYNFIRE4)
+        obs.configure(enabled=True, reset=True)
+        kp = be.assemble_fused(net.static, net.state0.weights,
+                               net.params).kernel
+        g = obs.registry().get("repro_fused_csr_chunks")
+        assert g.value(walk="window") == kp.csr_chunks == 30
+        assert g.value(walk="row") == kp.csr_row_chunks == 182
+        assert "repro_fused_csr_chunks{walk=\"window\"} 30" in (
+            obs.registry().to_prometheus())
 
     def test_kernel_ineligible_when_plastic(self):
         net = _build("fp16", "fused", stdp_chain=CHAIN_STDP)

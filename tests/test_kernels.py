@@ -13,7 +13,7 @@ from repro.kernels.flash_attn import flash_attention
 from repro.kernels.izh_update import izh4_update
 from repro.kernels.stdp_gather import stdp_gather
 from repro.kernels.stdp_update import stdp_update
-from repro.kernels.syn_gather import syn_gather
+from repro.kernels.syn_gather import lane_take, syn_gather
 from repro.kernels.syn_matmul import syn_matmul
 
 I = True  # interpret mode (CPU container; kernels target TPU)
@@ -150,6 +150,90 @@ class TestSynGather:
                          jnp.zeros((4, 0), jnp.int32),
                          jnp.zeros((4, 0), jnp.float32), interpret=I)
         np.testing.assert_array_equal(np.asarray(out), np.zeros(4, np.float32))
+
+
+class TestLaneTake:
+    """``lane_take`` on its own, inside a Pallas program: the whole-row
+    default and a window read from SMEM (traced loop bounds)."""
+
+    N, R, F = 1024, 16, 256  # 8 chunks; two 128-column blocks of indices
+
+    @staticmethod
+    def _take(row, idx, window=None):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        def kern(m_ref, row_ref, idx_ref, o_ref):
+            if window is None:
+                o_ref[...] = lane_take(row_ref, idx_ref[...])
+            else:
+                o_ref[...] = lane_take(row_ref, idx_ref[...],
+                                       base=m_ref[0], n_chunks=m_ref[1])
+
+        m = jnp.asarray(window or (0, 0), jnp.int32)
+        whole = lambda i, m: (0, 0)
+        return pl.pallas_call(
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(row.shape, whole),
+                          pl.BlockSpec(idx.shape, whole)],
+                out_specs=pl.BlockSpec(idx.shape, whole)),
+            out_shape=jax.ShapeDtypeStruct(idx.shape, jnp.float32),
+            interpret=I)(m, row, idx)
+
+    def _row(self, rng):
+        # distinct, exactly representable, none zero: a read from the
+        # wrong chunk or lane cannot go unnoticed
+        return (np.arange(1, self.N + 1) * rng.choice([-1.0, 1.0], self.N)
+                ).astype(np.float32)[None]
+
+    def test_whole_row_is_take(self):
+        rng = np.random.default_rng(0)
+        row = self._row(rng)
+        idx = rng.integers(0, self.N, (self.R, self.F)).astype(np.int32)
+        got = np.asarray(self._take(jnp.asarray(row), jnp.asarray(idx)))
+        np.testing.assert_array_equal(got, np.take(row[0], idx))
+
+    @pytest.mark.parametrize("base,n_chunks", [(0, 1), (256, 3), (768, 2),
+                                               (0, 8)])
+    def test_window_is_take_inside_zero_outside(self, base, n_chunks):
+        rng = np.random.default_rng(base + n_chunks)
+        row = self._row(rng)
+        hi = base + 128 * n_chunks
+        # inside the window (off its lane boundaries too), and outside it
+        idx = rng.integers(0, self.N, (self.R, self.F)).astype(np.int32)
+        idx[:, :8] = rng.integers(base, hi, (self.R, 8))
+        got = np.asarray(self._take(jnp.asarray(row), jnp.asarray(idx),
+                                    (base, n_chunks)))
+        inside = (idx >= base) & (idx < hi)
+        assert inside.any() and (n_chunks == 8 or (~inside).any())
+        np.testing.assert_array_equal(got[inside],
+                                      np.take(row[0], idx[inside]))
+        np.testing.assert_array_equal(got[~inside], 0.0)
+
+    def test_window_pads_give_the_whole_row_products(self):
+        """The megakernel's contract: live cells inside the window, pads
+        ``idx 0`` / weight ``+0.0`` anywhere; every product and row sum
+        is bitwise the whole row's, even with spike 0 firing."""
+        rng = np.random.default_rng(5)
+        base, n_chunks = 384, 3
+        spikes = (rng.random(self.N) < 0.5).astype(np.float32)
+        spikes[0] = 1.0
+        live = rng.random((self.R, self.F)) < 0.4
+        idx = np.where(live, rng.integers(base + 5, base + 128 * n_chunks,
+                                          (self.R, self.F)), 0)
+        idx = idx.astype(np.int32)
+        w = np.where(live, rng.integers(1, 9, (self.R, self.F)) * 0.25,
+                     0.0).astype(np.float32)
+        row, idx_j = jnp.asarray(spikes[None]), jnp.asarray(idx)
+        win = np.asarray(self._take(row, idx_j, (base, n_chunks))) * w
+        full = np.asarray(self._take(row, idx_j)) * w
+        np.testing.assert_array_equal(win.view(np.uint32),
+                                      full.view(np.uint32))
+        np.testing.assert_array_equal(win.sum(axis=1).view(np.uint32),
+                                      full.sum(axis=1).view(np.uint32))
+        assert not np.signbit(win).any()
 
 
 class TestFlashAttention:
@@ -345,23 +429,41 @@ class TestFusedTickKernel:
     kernel's lane padding / tile schedule / clamped DMAs must cancel out
     perfectly against the oracle's unpadded arithmetic."""
 
-    def _net(self, policy, prop):
+    def _net(self, policy, prop, cfg_name="SYNFIRE4_MINI"):
         import dataclasses
 
-        from repro.configs.synfire4 import SYNFIRE4_MINI, build_synfire
-        net = build_synfire(SYNFIRE4_MINI, policy=policy, backend="fused",
-                            propagation=prop)
+        from repro.configs import synfire4
+        net = synfire4.build_synfire(getattr(synfire4, cfg_name),
+                                     policy=policy, backend="fused",
+                                     propagation=prop)
         static = dataclasses.replace(net.static, fused_kernel=True)
         return dataclasses.replace(net, static=static)
 
     @pytest.mark.parametrize("prop", ["packed", "sparse"])
     @pytest.mark.parametrize("policy", ["fp32", "fp16"])
     def test_matches_ref_bitwise(self, prop, policy):
+        self._assert_matches_ref(self._net(policy, prop), prop)
+
+    @pytest.mark.parametrize("policy", ["fp32", "fp16"])
+    def test_full_synfire4_windowed_csr_matches_ref_bitwise(self, policy):
+        """Full Synfire4 in CSR rows: every row tile gathers from a window
+        of a few chunks of the ~14-chunk spike row, most of them starting
+        off a lane boundary."""
+        from repro.kernels import fused_tick as ftk
+
+        net = self._net(policy, "sparse", "SYNFIRE4")
+        kp = self._assert_matches_ref(net, "sparse")
+        csr = np.asarray(kp.meta)[np.asarray(kp.meta)[:, ftk._KIND] == 1]
+        assert (csr[:, ftk._NCH] < kp.n_pad // 128).all()
+        assert kp.csr_chunks < kp.csr_row_chunks
+        starts = [b.pre_start for b in net.static.buckets]
+        assert any(s % 128 for s in starts)
+
+    def _assert_matches_ref(self, net, prop):
         from repro.core import backend as be
         from repro.core import neurons as nrn
         from repro.kernels import fused_tick as ftk
 
-        net = self._net(policy, prop)
         static, params = net.static, net.params
         assert static.n % 128 != 0  # off the lane grid on purpose
         payload = be.assemble_fused(static, net.state0.weights, params)
@@ -408,6 +510,44 @@ class TestFusedTickKernel:
             np.testing.assert_array_equal(
                 np.asarray(o, np.float32), np.asarray(w, np.float32),
                 err_msg=f"fused tick kernel diverges from oracle on {name}")
+        return kp
+
+    def test_x10_windows_cover_live_indices(self):
+        """Synfire4×10's schedule: 46 CSR row tiles whose windows hold
+        every live index, 605 chunk passes per tick against 4,508 for the
+        whole 98-chunk row; a live index moved out of its window is
+        refused."""
+        import dataclasses
+
+        from repro.configs.synfire4 import SYNFIRE4_X10, build_synfire
+        from repro.core import backend as be
+        from repro.kernels import fused_tick as ftk
+
+        net = build_synfire(SYNFIRE4_X10, policy="fp16", backend="fused",
+                            propagation="sparse", budget=None,
+                            monitor_ms_hint=0)
+        static = dataclasses.replace(net.static, fused_kernel=True)
+        kp = be.assemble_fused(static, net.state0.weights,
+                               net.params).kernel  # checks the windows
+        assert (kp.n_pad, kp.n_steps) == (12544, 46)
+        assert (kp.csr_chunks, kp.csr_row_chunks) == (605, 4508)
+
+        meta = np.asarray(kp.meta)
+        idx, w = np.asarray(kp.csr_idx), np.asarray(kp.csr_w)
+        tile = np.repeat(np.arange(kp.n_steps), kp.tile_r)
+        lo = meta[tile, ftk._PRE][:, None]
+        hi = lo + 128 * meta[tile, ftk._NCH][:, None]
+        live = w != 0
+        assert live.sum() > 890_000  # of 900,000 synapses; a few weigh 0
+        assert ((idx[live] >= np.broadcast_to(lo, idx.shape)[live])
+                & (idx[live] < np.broadcast_to(hi, idx.shape)[live])).all()
+
+        r, f = np.argwhere(live)[0]
+        bad = idx.copy()
+        bad[r, f] = hi[r, 0]  # the first lane past the window
+        with pytest.raises(ValueError, match="outside its tile's gather"):
+            ftk._check_windows(meta, bad, w, kp.tile_r)
+        ftk._check_windows(meta, idx, w, kp.tile_r)
 
 
 class TestFlashAttentionStress:

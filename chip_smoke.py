@@ -19,6 +19,13 @@ One chip:
 * serve — 64 Synfire4 tenants in one ``LaneScheduler``, five chunks of
   100 ticks with a flush after each; every flush must equal a solo
   ``Session`` of the same seed bit for bit.
+* plastic — Izhikevich's polychronization network (2006, 1,000 neurons,
+  per-synapse delays, STDP with a once-a-second weight update) in fp16
+  (``xla`` and ``pallas``) and fp32, two 1,000-tick ``Session`` chunks
+  against the benchmark's plain reference
+  (``bench/references/izhikevich2006.py``) on the same chip: the spike
+  counts of each chunk must be equal, the plastic weights equal bit for bit
+  after both weight updates, and each flush must report one update.
 
 Four chips (``--chips 4``):
 
@@ -52,6 +59,7 @@ PAPER_ACCURACY = 0.975  # paper §III-A, fp16 vs fp32 spike counts
 TICKS = 1000
 CHUNK, N_CHUNKS, N_TENANTS = 100, 5, 64
 MESH_TICKS, MESH_LANES, LANE_CHUNKS = 200, 512, 2
+PLASTIC_CHUNKS = 2  # two weight updates of the polychronization network
 
 
 def log(phase: str, **kw) -> None:
@@ -215,6 +223,72 @@ def serve_phase(cfg, n_tenants: int, chunk: int, n_chunks: int) -> None:
                              f"{total})")
 
 
+def plastic_phase(n_chunks: int, chunk: int) -> None:
+    """The polychronization network's Sessions vs the plain reference."""
+    import dataclasses
+    from pathlib import Path
+
+    import jax
+    import numpy as np
+
+    sys.path.insert(0, ROOT)
+    from bench import harness
+    from repro import obs
+    from repro.configs.izhikevich2006 import IZH2006, build_izhikevich2006
+    from repro.serve import Session
+
+    ref = harness.load_module(Path(ROOT, "bench", "references",
+                                   "izhikevich2006.py"))
+    seed, stream = 11, 5
+    network = {k: v for k, v in dataclasses.asdict(IZH2006).items()
+               if k != "name"}
+    refs = {}
+    for policy, storage, backend in (("fp16", "float16", "xla"),
+                                     ("fp16", "float16", "pallas"),
+                                     ("fp32", "float32", "xla")):
+        net = build_izhikevich2006(IZH2006, policy=policy, seed=seed,
+                                   backend=backend)
+        session = Session.create(net, seed=stream)
+        counts, weights, updates, shares = [], [], [], []
+        counter = obs.registry().counter("repro_stdp_weight_updates_total")
+        c0 = counter.value() or 0.0
+        t0 = time.perf_counter()
+        for _ in range(n_chunks):
+            session.run(chunk)
+            jax.block_until_ready(session.state)
+            out = session.flush()
+            counts.append(np.asarray(out["spike_count"]))
+            updates.append(out["weight_updates"])
+            shares.append(np.asarray(out["plastic_weights"])[0].tolist())
+            weights.append(np.asarray(session.state.weights[0]))
+        wall = time.perf_counter() - t0
+        counted = (counter.value() or 0.0) - c0
+        t0 = time.perf_counter()
+        if storage not in refs:
+            refs[storage] = ref.simulate(
+                ref.build({**network, "storage_dtype": storage}, seed),
+                [stream], n_chunks, chunk, weights=True)
+        want_counts, want_w = refs[storage]
+        ref_wall = time.perf_counter() - t0
+        counts_eq = np.array_equal(np.stack(counts), want_counts[0])
+        w_eq = all(g.shape == w.shape and g.tobytes() == w.tobytes()
+                   for g, w in zip(weights, want_w[0]))
+        log("plastic", net=IZH2006.name, policy=policy,
+            backend=net.static.backend, ticks=n_chunks * chunk,
+            wall_s=round(wall, 3), reference_wall_s=round(ref_wall, 3),
+            spikes=np.stack(counts).tolist(),
+            reference_spikes=want_counts[0].tolist(),
+            counts_equal=counts_eq, weights_bitwise=w_eq,
+            weight_updates=updates, counter_rise=counted,
+            mean_w_share0_share10=shares)
+        if (not counts_eq or not w_eq or not np.stack(counts).sum()
+                or updates != [chunk // 1000] * n_chunks
+                or counted != sum(updates)):
+            raise AssertionError(f"{IZH2006.name}/{policy}/{backend}: "
+                                 "spike counts, weights or weight updates "
+                                 "differ from the reference on the chip")
+
+
 def _device_sets(tree) -> list[int]:
     import jax
     return sorted({len(x.sharding.device_set) for x in jax.tree.leaves(tree)
@@ -328,6 +402,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.chips == 1:
         engine_phase(SYNFIRE4, SYNFIRE4_X10, TICKS, SPIKE_BAND)
         serve_phase(SYNFIRE4, N_TENANTS, CHUNK, N_CHUNKS)
+        plastic_phase(PLASTIC_CHUNKS, TICKS)
     else:
         partition_mesh_phase(scale_synfire(SYNFIRE4, 100), 4, MESH_TICKS)
         lane_mesh_phase(SYNFIRE4, MESH_LANES, 4, CHUNK, LANE_CHUNKS)

@@ -319,7 +319,8 @@ def propagate_packed(static, params, state, spikes, ring, t, packed,
     new_stp = []
     for j, (spec, w, stp_state) in enumerate(
             zip(static.projections, state.weights, state.stp)):
-        if not (spec.plastic or spec.stp is not None):
+        if not (spec.plastic or spec.stp is not None) or spec.synapse_delays:
+            # (per-synapse-delay projections are read at delivery instead)
             new_stp.append(None)
             continue
         pre_sp = spikes_f32[spec.pre_slice]

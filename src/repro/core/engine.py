@@ -88,8 +88,12 @@ from repro.telemetry import monitors as tel
 from repro.core.conductance import coba_current, decay_and_deliver
 from repro.core.network import CompiledNetwork, NetParams, NetState, NetStatic
 from repro.core.plasticity import (
+    DelaySTDPConfig,
     da_stdp_step,
     da_stdp_step_csr,
+    delay_drive,
+    delay_history,
+    delay_stdp_step,
     homeostasis_step,
     homeostasis_step_csr,
 )
@@ -113,6 +117,7 @@ def step(
     *,
     packed: tuple[jax.Array, ...] | None = None,
     gen_u: jax.Array | None = None,
+    kick: jax.Array | None = None,
 ) -> tuple[NetState, StepOutput]:
     """One 1 ms tick. Pure; jit/scan-friendly.
 
@@ -129,6 +134,10 @@ def step(
     seed behavior, kept only for direct ``step`` calls. The two modes
     consume different RNG streams, so their rasters differ
     realization-wise (not statistically).
+
+    ``kick`` is this tick's ``[static.kick_per_tick]`` kicked neuron ids
+    (``run`` draws them; ids >= N are dropped): each gets
+    ``static.kick_amp`` of input, added after the synaptic current.
     """
     f32 = jnp.float32
     t = state.t
@@ -162,8 +171,20 @@ def step(
         i_syn = coba_current(static.coba, cond, state.neurons.v)
     else:
         i_syn = deliver[:, 0]
+    # Per-synapse-delay projections: the drive due now is read from each
+    # pre group's trace history, added after the ring's delivery in
+    # projection order. The gathered history also serves phase 6.
+    hist = {}
+    for j in static.delay_csr:
+        spec = static.projections[j]
+        hist[j] = delay_history(state.stdp[j], params.proj_hist_idx[j])
+        i_syn = i_syn.at[spec.post_slice].add(
+            delay_drive(static.stdp[j], hist[j], state.weights[j]))
     if i_ext is not None:
         i_syn = i_syn + i_ext.astype(f32)
+    if kick is not None:
+        i_syn = i_syn + jnp.zeros((static.n,), f32).at[kick].set(
+            static.kick_amp, mode="drop")
 
     # 3: neuron dynamics (xla reference or fused pallas IZH4 kernel)
     new_neurons, spiked = be.update_neurons_dispatch(
@@ -234,6 +255,12 @@ def step(
             continue
         pre_sp = spikes[spec.pre_slice]
         post_sp = spikes[spec.post_slice]
+        if isinstance(cfg, DelaySTDPConfig):
+            tr2, w2 = delay_stdp_step(cfg, tr, w, hist[j], mask, pre_sp,
+                                      post_sp, t)
+            new_weights.append(w2)
+            new_stdp.append(tr2)
+            continue
         idx = params.proj_csr_idx[j] if j in static.csr_projs else None
         if cfg.tau_elig is not None:
             if idx is not None:
@@ -508,6 +535,22 @@ def _run_impl(
         # Idle serving lanes draw no generator spikes (uniform 1.0 is never
         # < p): the network relaxes to rest and emits no events.
         gu_xs = jnp.where(active, gu_xs, 1.0)
+    # Kick input: tick t's ids are ``randint(fold_in(base, t), (k,), 0, N)``
+    # with t the absolute tick and base the session stream (``gen_base``),
+    # else a key split from the carry — compile() keeps kicks and
+    # generators apart, so the two never share a stream.
+    kick_xs = jnp.zeros((n_steps, 0), jnp.int32)
+    if static.kick_per_tick:
+        base = gen_base
+        if base is None:
+            base, k_carry = jax.random.split(state.key)
+            state = state._replace(key=k_carry)
+        ts = state.t + jnp.arange(n_steps, dtype=jnp.int32)
+        kick_xs = jax.vmap(lambda i: jax.random.randint(
+            jax.random.fold_in(base, i), (static.kick_per_tick,), 0,
+            static.n))(ts)
+        if active is not None:
+            kick_xs = jnp.where(active, kick_xs, static.n)  # dropped
 
     tel0 = (tel_carry if tel_carry is not None else
             tel.init_carry(static, n_steps)) if want_mon else ()
@@ -519,25 +562,27 @@ def _run_impl(
 
     def body_wrap(carry, xs):
         st, tel_c, wat_c, cnt = carry
-        ie, da, gu, ix = xs
+        ie, da, gu, kk, ix = xs
         ie = ie if ie.shape[-1] else None  # static shape: decided at trace time
         da = da[0] if da.shape[-1] else None
         gu = gu if gu.shape[-1] else None
+        kk = kk if kk.shape[-1] else None
         new_state, out = step(static, params, st, ie, da, packed=packed,
-                              gen_u=gu)
+                              gen_u=gu, kick=kk)
         if want_mon:
             # Monitors fold this tick's observables into the carry — pure
             # reads of the step output, so the dynamics (and the raster, if
             # also recorded) are untouched.
             tel_c, tel_ys = tel.update(static, tel_c, ix[0], out.spikes,
-                                       out.v, new_state.weights)
+                                       out.v, new_state.weights, t=st.t)
         else:
             tel_ys = None
         if want_watch:
             # Watchpoints are the same pure-read fold: O(1) health
             # reductions that never feed back into the dynamics.
             wat_c = wat.update(static, wat_c, ix[0], out.spikes,
-                               out.v, new_state.weights)
+                               out.v, new_state.weights,
+                               stdp=new_state.stdp)
         if has_homeo:
             cnt = cnt + out.spikes.astype(jnp.int32)
         ys = (out.spikes if want_raster else None,
@@ -554,7 +599,7 @@ def _run_impl(
     if seg_len is None:
         (final, tel_final, watch_final, _), ys = jax.lax.scan(
             body_wrap, (state, tel0, watch0, cnt0),
-            (ie_xs, da_xs, gu_xs, ix_xs), length=n_steps)
+            (ie_xs, da_xs, gu_xs, kick_xs, ix_xs), length=n_steps)
     else:
         n_seg = n_steps // seg_len
 
@@ -563,21 +608,22 @@ def _run_impl(
 
         if chunked:
             xs = (jax.random.split(k_draw, n_seg),
-                  resh(ie_xs), resh(da_xs), resh(ix_xs))
+                  resh(ie_xs), resh(da_xs), resh(kick_xs), resh(ix_xs))
         else:
-            xs = (resh(ie_xs), resh(da_xs), resh(gu_xs), resh(ix_xs))
+            xs = (resh(ie_xs), resh(da_xs), resh(gu_xs), resh(kick_xs),
+                  resh(ix_xs))
 
         def seg_body(carry, seg_xs):
             if chunked:
-                key_c, ie_c, da_c, ix_c = seg_xs
+                key_c, ie_c, da_c, kk_c, ix_c = seg_xs
                 gu_c = jax.random.uniform(key_c, (seg_len, static.n_gen),
                                           dtype=jnp.float32)
                 if active is not None:
                     gu_c = jnp.where(active, gu_c, 1.0)
             else:
-                ie_c, da_c, gu_c, ix_c = seg_xs
+                ie_c, da_c, gu_c, kk_c, ix_c = seg_xs
             carry, seg_ys = jax.lax.scan(body_wrap, carry,
-                                         (ie_c, da_c, gu_c, ix_c),
+                                         (ie_c, da_c, gu_c, kk_c, ix_c),
                                          length=seg_len)
             if has_homeo:
                 st, tel_c, wat_c, cnt = carry

@@ -29,10 +29,13 @@ from repro.core import neurons as nrn
 from repro.core.conductance import COBAConfig, ConductanceState, init_conductance_state
 from repro.core.plasticity import (
     DASTDPState,
+    DelaySTDPConfig,
+    DelaySTDPState,
     HomeostasisConfig,
     STDPConfig,
     STDPState,
     init_da_stdp_state,
+    init_delay_stdp_state,
     init_stdp_state,
 )
 from repro.core.synapses import (
@@ -43,6 +46,7 @@ from repro.core.synapses import (
     STPState,
     build_bernoulli,
     build_csr_direct,
+    build_fanout,
     build_fixed_fanin,
     csr_layout,
     dense_to_csr,
@@ -142,6 +146,7 @@ def _plan_fused(
     channels: int,
     izh4_only: bool,
     method: str,
+    kicks: bool = False,
 ) -> FusedPlan:
     delays = sorted({b.delay_ms for b in buckets} | {
         s.delay_ms for s in specs if s.plastic or s.stp is not None
@@ -163,6 +168,7 @@ def _plan_fused(
          "plastic or STP projections; their weights change every tick"),
         (not all(b.pre_start >= 0 and b.post_start >= 0 for b in buckets),
          "a bucket with a non-contiguous pre or post span"),
+        (kicks, "kick input; the kernel draws no kicks"),
     )
     reason = next((why for refused, why in refusals if refused), "")
     # Tile geometry: the widest streamed buffer must fit _VMEM_TILE_BYTES.
@@ -224,7 +230,7 @@ class NetStatic:
     policy_name: str
     groups: tuple[GroupSpec, ...]
     projections: tuple[ProjectionSpec, ...]
-    stdp: tuple[STDPConfig | None, ...]  # aligned with projections
+    stdp: tuple[STDPConfig | DelaySTDPConfig | None, ...]  # per projection
     coba: COBAConfig | None = None
     # -- execution strategy (see repro.core.backend) --------------------------
     backend: str = "xla"  # "xla" | "pallas" | "fused"
@@ -268,6 +274,17 @@ class NetStatic:
     # run and returns them as outputs["watch_carry"]. Pure reads of the
     # step output — outputs stay bitwise identical watch-on vs watch-off.
     watches: tuple = ()
+    # Projections with a delay per synapse (``ProjectionSpec.synapse_delays``,
+    # all plastic under DelaySTDPConfig): stored fan-in-major ``[fanin,
+    # post]``, their drive read at delivery from the pre neurons' trace
+    # history (``NetParams.proj_hist_idx``), never through the delay ring.
+    delay_csr: tuple[int, ...] = ()
+    # External "thalamic" input (Izhikevich 2006): every tick,
+    # ``kick_per_tick`` neurons drawn uniformly from all N get
+    # ``kick_amp`` of input (set semantics: a neuron drawn twice gets it
+    # once). 0 = none.
+    kick_per_tick: int = 0
+    kick_amp: float = 0.0
 
     @property
     def gen_spans(self) -> tuple[tuple[int, int], ...]:
@@ -330,6 +347,10 @@ class NetParams(NamedTuple):
     # row/slot) instead of the CSR 0-pad, so padded drive terms are exact
     # +0.0 in both storages and dense↔CSR rasters stay bit-identical.
     proj_csr_idx: tuple[jax.Array | None, ...] = ()
+    # Per projection, for ``static.delay_csr`` members: ``[fanin, post]``
+    # int32 ``(delay - 1) * pre_size + pre`` — each synapse's entry in its
+    # pre group's ``[max_delay, pre]`` trace history (0 on padding).
+    proj_hist_idx: tuple[jax.Array | None, ...] = ()
 
 
 class NetState(NamedTuple):
@@ -339,7 +360,7 @@ class NetState(NamedTuple):
     ring: jax.Array  # [D, N, C] storage dtype
     weights: tuple[jax.Array, ...]  # per projection [pre, post] storage dtype
     stp: tuple[STPState | None, ...]
-    stdp: tuple[STDPState | DASTDPState | None, ...]
+    stdp: tuple[STDPState | DASTDPState | DelaySTDPState | None, ...]
     cond: ConductanceState | None
     # Per-projection homeostasis running-average firing rate [post_size]
     # f32 (None where static.homeo[j] is None). Lives in NetState so the
@@ -358,8 +379,12 @@ class _PendingConnect:
     stdp: STDPConfig | None
     stp: STPConfig | None
     da_modulated: bool
-    mode: str = "fanin"  # "fanin" (exact) | "prob" (CARLsim random connect)
+    # "fanin" (exact) | "prob" (CARLsim random connect) | "fanout" (exact
+    # per pre neuron, spnet)
+    mode: str = "fanin"
     homeostasis: HomeostasisConfig | None = None
+    fanout: int = 0
+    delays: tuple[int, ...] | None = None  # per outgoing synapse slot
 
 
 class NetworkBuilder:
@@ -370,6 +395,7 @@ class NetworkBuilder:
         self._connects: list[_PendingConnect] = []
         self._cursor = 0
         self._seed = seed
+        self._kicks = (0, 0.0)
 
     # -- groups ---------------------------------------------------------------
     def add_group(self, name: str, params: nrn.NeuronParams) -> str:
@@ -392,31 +418,81 @@ class NetworkBuilder:
         self._cursor += size
         return name
 
+    def add_kicks(self, per_tick: int, amplitude: float) -> None:
+        """Random "thalamic" input (Izhikevich 2006): every tick,
+        ``per_tick`` neurons drawn uniformly from all N (index draws from
+        the stimulus stream, see ``engine.run``) get ``amplitude`` of
+        input, once even if drawn twice."""
+        if per_tick < 1:
+            raise ValueError(f"per_tick must be >= 1, got {per_tick}")
+        self._kicks = (int(per_tick), float(amplitude))
+
     # -- connections ------------------------------------------------------------
     def connect(
         self,
         pre: str,
-        post: str,
+        post: str | tuple[str, ...],
         *,
-        fanin: int,
+        fanin: int = 0,
         weight: float,
-        delay_ms: int,
+        delay_ms: int | tuple[int, ...],
         plastic: bool = False,
-        stdp: STDPConfig | None = None,
+        stdp: STDPConfig | DelaySTDPConfig | None = None,
         stp: STPConfig | None = None,
         da_modulated: bool = False,
         mode: str = "fanin",
         homeostasis: HomeostasisConfig | None = None,
+        fanout: int = 0,
     ) -> None:
-        if delay_ms < 1:
+        """Add a connection group. ``post`` may name adjacent groups, whose
+        span it then covers. ``mode="fanout"`` gives each pre neuron
+        ``fanout`` distinct targets (:func:`~repro.core.synapses.build_fanout`);
+        there ``delay_ms`` may instead be a sequence of ``fanout`` delays,
+        one per outgoing synapse slot, for a projection under
+        :class:`DelaySTDPConfig`."""
+        delays = None
+        if not isinstance(delay_ms, (int, np.integer)):
+            delays = tuple(int(d) for d in delay_ms)
+            if mode != "fanout" or len(delays) != fanout:
+                raise ValueError("per-synapse delays need mode='fanout' and "
+                                 "one delay per outgoing synapse slot")
+            if not isinstance(stdp, DelaySTDPConfig):
+                raise ValueError("per-synapse delays are implemented for "
+                                 "DelaySTDPConfig projections only")
+            delay_ms = max(delays)
+        if min(delays or (delay_ms,)) < 1:
             raise ValueError("delay must be >= 1 ms (one tick)")
+        if mode == "fanout" and fanout < 1:
+            raise ValueError("mode='fanout' needs fanout >= 1")
+        if mode != "fanout" and fanin < 1:
+            raise ValueError(f"mode={mode!r} needs fanin >= 1")
+        if isinstance(stdp, DelaySTDPConfig) and (
+                delays is None or stp is not None or homeostasis is not None
+                or da_modulated or weight < 0):
+            raise ValueError("DelaySTDPConfig needs per-synapse delays, an "
+                             "excitatory weight, and no STP, homeostasis or "
+                             "dopamine")
         if homeostasis is not None and stp is not None:
             raise ValueError("homeostasis on STP projections is unsupported")
         self._connects.append(
             _PendingConnect(pre, post, fanin, weight, delay_ms,
                             plastic or stdp is not None or homeostasis is not None,
-                            stdp, stp, da_modulated, mode, homeostasis)
+                            stdp, stp, da_modulated, mode, homeostasis,
+                            fanout, delays)
         )
+
+    def _span(self, names: str | tuple[str, ...]) -> GroupSpec:
+        """The group ``names`` names, or the one span of adjacent groups."""
+        names = (names,) if isinstance(names, str) else tuple(names)
+        specs = [next(s for _, _, s in self._groups if s.name == nm)
+                 for nm in names]
+        for a, b in zip(specs, specs[1:]):
+            if b.start != a.start + a.size:
+                raise ValueError(f"groups {names} are not adjacent")
+        if len(specs) == 1:
+            return specs[0]
+        return GroupSpec(name="+".join(names), start=specs[0].start,
+                         size=sum(s.size for s in specs))
 
     # -- compile ------------------------------------------------------------------
     def compile(
@@ -457,6 +533,22 @@ class NetworkBuilder:
             raise ValueError(
                 "homeostasis_period set but no connection has a "
                 "HomeostasisConfig")
+        delayed = [c for c in self._connects if c.delays is not None]
+        if any(c.mode == "fanout" for c in self._connects) and (
+                propagation != "sparse"):
+            raise ValueError("mode='fanout' builds CSR fan-in rows: compile "
+                             "with propagation='sparse'")
+        if delayed and (backend == "fused" or conductances is not None
+                        or partition is not None):
+            raise ValueError(
+                "per-synapse delays run on the xla/pallas multi-op path, "
+                "CUBA, unpartitioned")
+        n_kick = self._kicks[0]
+        if n_kick and any(spec.is_generator for _, _, spec in self._groups):
+            raise ValueError("kick input and generator groups would share "
+                             "the stimulus key: use one or the other")
+        if n_kick and partition is not None:
+            raise ValueError("kick input runs unpartitioned")
         pallas_interpret = kops.resolve_interpret(pallas_interpret)
         if isinstance(policy, str):
             policy = get_policy(policy)
@@ -499,18 +591,25 @@ class NetworkBuilder:
         stdp_cfgs: list[STDPConfig | None] = []
         homeo_cfgs: list[HomeostasisConfig | None] = []
         for c in self._connects:
-            gpre = next(s for _, _, s in self._groups if s.name == c.pre)
-            gpost = next(s for _, _, s in self._groups if s.name == c.post)
+            gpre = self._span(c.pre)
+            gpost = self._span(c.post)
             receptor = "inh" if c.weight < 0 else "exc"
+            if c.delays is not None and dt != 1.0:
+                raise ValueError("per-synapse delays are in ticks of 1 ms")
             spec = ProjectionSpec(
-                name=f"{c.pre}->{c.post}",
+                name=f"{gpre.name}->{gpost.name}",
                 pre_start=gpre.start, pre_size=gpre.size,
                 post_start=gpost.start, post_size=gpost.size,
                 delay_ms=int(round(c.delay_ms / dt)),
                 receptor=receptor, plastic=c.plastic, stp=c.stp,
+                synapse_delays=c.delays is not None,
             )
             specs.append(spec)
-            if gpre.size * gpost.size > _DENSE_BUILD_CELLS:
+            if c.mode == "fanout":
+                projs.append(build_fanout(rng, spec, c.fanout, c.weight,
+                                          delays=c.delays,
+                                          storage_dtype=wdt))
+            elif gpre.size * gpost.size > _DENSE_BUILD_CELLS:
                 # Too big to materialize the dense [pre, post] mask on the
                 # host (a Synfire4×100 layer is 4e8 cells) — sample the
                 # fan-in rows directly. Bitwise-different draws from the
@@ -551,10 +650,11 @@ class NetworkBuilder:
         # and DA eligibility all shrink to [post, fanin].
         plastic_csr = tuple(sorted(
             j for j, s in enumerate(specs)
-            if s.plastic and s.stp is None
+            if s.plastic and s.stp is None and not s.synapse_delays
             and (propagation == "sparse"
                  or (propagation == "auto" and _csr_wins(s)))
         ))
+        delay_csr = tuple(j for j, s in enumerate(specs) if s.synapse_delays)
         # STP projections go CSR in *every* non-loop mode: their per-pre
         # u·x scale composes with the fan-in gather (scale the pre spike
         # row, then gather), so the drive shares the plastic fan-in-row
@@ -565,7 +665,7 @@ class NetworkBuilder:
         ))
         csr_set = frozenset(
             m[0] for b in buckets if b.kind == "sparse" for m in b.members
-        ) | frozenset(plastic_csr) | frozenset(stp_csr)
+        ) | frozenset(plastic_csr) | frozenset(stp_csr) | frozenset(delay_csr)
         for j, p in enumerate(projs):
             if isinstance(p, CSRFanin) and j not in csr_set:
                 raise ValueError(
@@ -592,7 +692,9 @@ class NetworkBuilder:
         # bit-identical across propagation modes.
         proj_csr_idx: list[jax.Array | None] = []
         for j, s in enumerate(specs):
-            if j in csr_set:
+            if j in delay_csr:
+                proj_csr_idx.append(None)
+            elif j in csr_set:
                 proj_csr_idx.append(csr[j].idx)
             elif s.plastic and s.stp is None and propagation != "loop":
                 # Index geometry only — no quantized weight rows, no device
@@ -604,26 +706,40 @@ class NetworkBuilder:
                 proj_csr_idx.append(jnp.asarray(sent.astype(idt)))
             else:
                 proj_csr_idx.append(None)
+        # Per-synapse-delay projections go fan-in-major ([fanin, post]):
+        # each tick sums a row's entries one slot after another, and a slot
+        # across all post neurons is then one contiguous vector.
+        proj_hist_idx = tuple(
+            jnp.asarray(np.where(
+                csr[j].valid,
+                (csr[j].delay - 1) * specs[j].pre_size
+                + np.asarray(csr[j].idx, np.int32), 0).T.astype(np.int32))
+            if j in delay_csr else None
+            for j in range(len(specs)))
         # Validity rows go on device only for plastic CSR projections (the
         # STDP mask); non-plastic CSR builds never pay the transfer.
         masks = tuple(
-            jnp.asarray(csr[j].valid) if j in csr_set and p_spec.plastic
+            jnp.asarray(csr[j].valid.T) if j in delay_csr
+            else jnp.asarray(csr[j].valid) if j in csr_set and p_spec.plastic
             else (None if j in csr_set else p.mask)
             for j, (p_spec, p) in enumerate(zip(specs, projs))
         )
         weights = tuple(
-            csr[j].weight if j in csr_set else p.weight
+            csr[j].weight.T if j in delay_csr
+            else csr[j].weight if j in csr_set else p.weight
             for j, p in enumerate(projs)
         )
         with ledger.stage("3. Conn. Info"):
             ledger.register("masks", tuple(m for m in masks if m is not None))
-            idx_tables = tuple(t for t in proj_csr_idx if t is not None)
+            idx_tables = tuple(t for t in (*proj_csr_idx, *proj_hist_idx)
+                               if t is not None)
             if idx_tables:
                 ledger.register("csr.indices", idx_tables)
 
         # 4. Syn. State — weights (the fp16 payload; CSR rows for sparse
         # projections), delay ring, STP.
-        max_delay = max((s.delay_ms for s in specs), default=1)
+        max_delay = max((s.delay_ms for s in specs if not s.synapse_delays),
+                        default=1)
         ring_len = max_delay + 1
         ring = jnp.zeros((ring_len, n, channels), sdt)
         stp_states: list[STPState | None] = [
@@ -657,6 +773,10 @@ class NetworkBuilder:
         for j, (spec, cfg) in enumerate(zip(specs, stdp_cfgs)):
             if cfg is None:
                 stdp_states.append(None)
+            elif isinstance(cfg, DelaySTDPConfig):
+                stdp_states.append(init_delay_stdp_state(
+                    spec.pre_size, spec.post_size, spec.delay_ms, spec.fanin,
+                    wdt))
             elif cfg.tau_elig is not None:
                 # CSR-stored projections carry eligibility on the fan-in
                 # rows — [post, fanin] instead of the [pre, post] rectangle.
@@ -727,7 +847,7 @@ class NetworkBuilder:
         fused_kernel = False
         if backend == "fused":
             fused = _plan_fused(buckets, tuple(specs), channels,
-                                izh4_only, method)
+                                izh4_only, method, kicks=bool(n_kick))
             # The Pallas program engages on TPU (native lowering) or when
             # CI forces interpret execution; other CPU runs take the
             # single-dispatch XLA expression of the same plan.
@@ -744,7 +864,8 @@ class NetworkBuilder:
             buckets=buckets, plastic_csr=plastic_csr, stp_csr=stp_csr,
             fused=fused, fused_kernel=fused_kernel, monitors=mon_specs,
             homeo=tuple(homeo_cfgs), homeo_period=int(homeostasis_period),
-            watches=watch_specs,
+            watches=watch_specs, delay_csr=delay_csr,
+            kick_per_tick=n_kick, kick_amp=self._kicks[1],
         )
         params = NetParams(
             neuron=neuron_params,
@@ -756,6 +877,7 @@ class NetworkBuilder:
             bucket_post_ids=post_ids,
             bucket_csr_idx=bucket_csr_idx,
             proj_csr_idx=tuple(proj_csr_idx),
+            proj_hist_idx=proj_hist_idx,
         )
         state0 = NetState(
             t=jnp.int32(0), key=key, neurons=nstate, ring=ring,

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 
 __all__ = ["STDPConfig", "STDPState", "stdp_step", "stdp_step_csr",
            "DASTDPState", "da_stdp_step", "da_stdp_step_csr",
+           "DelaySTDPConfig", "DelaySTDPState", "init_delay_stdp_state",
+           "delay_history", "delay_drive", "delay_stdp_step",
            "HomeostasisConfig", "homeostasis_step", "homeostasis_step_csr"]
 
 
@@ -212,6 +214,144 @@ def da_stdp_step_csr(
     new = DASTDPState(pre_trace=pre_t, post_trace=post_t,
                       elig=elig.astype(state.elig.dtype))
     return new, w
+
+
+# -- Izhikevich (2006) STDP on per-synapse delays --------------------------------
+#
+# The polychronization network's rule (``spnet.cpp``), on projections whose
+# synapses each carry their own axonal delay. Its weights, accumulator and
+# tables are stored fan-in-major, ``[fanin, n_post]``: column ``q`` is post
+# neuron ``q``'s fan-in row, entries in ascending (pre, delay) order with
+# the row's padding at its end.
+
+
+@dataclasses.dataclass(frozen=True)
+class DelaySTDPConfig:
+    """Nearest-spike STDP with a once-a-period weight update (Izhikevich,
+    *Polychronization: computation with spikes*, 2006; CARLsim's weight
+    and weight-change update interval).
+
+    Each pre neuron keeps a trace LTP, set to ``ltp_amp`` on its spike tick
+    and multiplied by ``decay`` at every later tick; each post neuron a
+    trace LTD, likewise with ``ltd_amp``. Per synapse ``p -> q`` of delay
+    ``d``: when ``q`` fires at ``t``, ``sd += LTP_p`` as it stood after tick
+    ``t - d``; when a spike of ``p`` arrives at ``t`` (emitted at
+    ``t - d``), ``sd -= LTD_q`` as it stood after tick ``t - 1``. At every
+    tick with ``t % update_every == update_every - 1``, after that tick's
+    terms: ``w <- clip((w + bias) + sd, w_min, w_max)``, then
+    ``sd <- sd_decay * sd``.
+    """
+
+    ltp_amp: float = 0.1
+    ltd_amp: float = 0.12
+    decay: float = 0.95
+    update_every: int = 1000
+    bias: float = 0.01
+    sd_decay: float = 0.9
+    w_min: float = 0.0
+    w_max: float = 10.0
+
+    def __post_init__(self):
+        # A trace equals ltp_amp only on its spike tick (decay < 1), which
+        # is how the history doubles as the pre neuron's spike record.
+        if not (self.ltp_amp > 0 and 0 < self.decay < 1):
+            raise ValueError("DelaySTDPConfig needs ltp_amp > 0 and "
+                             "0 < decay < 1")
+        if self.update_every < 1:
+            raise ValueError("update_every must be >= 1")
+
+
+class DelaySTDPState(NamedTuple):
+    # [max_delay, n_pre] f32: row r holds each pre neuron's LTP trace as it
+    # stood after tick t - 1 - r (at the start of tick t). A trace equals
+    # ltp_amp exactly on its spike tick, so the history is also the record
+    # of which pre neurons fired at t - 1 - r.
+    ltp_hist: jax.Array
+    ltd: jax.Array  # [n_post] f32: each post neuron's LTD after tick t - 1
+    sd: jax.Array  # [fanin, n_post] weight dtype: weight-change accumulator
+
+
+def init_delay_stdp_state(n_pre: int, n_post: int, max_delay: int,
+                          fanin: int, dtype=jnp.float32) -> DelaySTDPState:
+    return DelaySTDPState(
+        ltp_hist=jnp.zeros((max_delay, n_pre), jnp.float32),
+        ltd=jnp.zeros((n_post,), jnp.float32),
+        sd=jnp.zeros((fanin, n_post), dtype),
+    )
+
+
+def _ordered_sum(terms: jax.Array) -> jax.Array:
+    """``terms[0] + terms[1] + ... + terms[-1]`` over the leading axis, in
+    float32 and in that order: the association is part of the result, so
+    the same spikes and weights give the same sum on every backend (a
+    ``sum`` reduction's order depends on shape and device)."""
+    acc = terms[0]
+    for k in range(1, terms.shape[0]):
+        acc = acc + terms[k]
+    return acc
+
+
+def delay_history(state: DelaySTDPState, hist_idx: jax.Array) -> jax.Array:
+    """Per synapse, the pre neuron's LTP trace as it stood after tick
+    ``t - d`` (``d`` its delay): ``hist_idx [F, Q]`` holds
+    ``(d - 1) * n_pre + pre``. Padding reads row 0, pre 0 and is masked by
+    every consumer.
+
+    The history is read as rows of two entries, the one wanted picked by
+    the index's parity: XLA:TPU gathers 2-element rows ~2.8x faster than
+    single elements (1.28 M slots: 3.3 ms against 9.2 ms on a v5e)."""
+    flat = state.ltp_hist.reshape(-1)
+    pairs = jnp.take(jnp.pad(flat, (0, flat.size % 2)).reshape(-1, 2),
+                     hist_idx // 2, axis=0)
+    return jnp.where(hist_idx % 2 == 0, pairs[..., 0], pairs[..., 1])
+
+
+def delay_drive(cfg: DelaySTDPConfig, hist: jax.Array,
+                weight: jax.Array) -> jax.Array:
+    """Drive ``[n_post]`` due at tick ``t``: each post neuron's fan-in row
+    summed left to right over the synapses whose pre spike arrives now
+    (emitted at ``t - d``, i.e. the trace after ``t - d`` equals
+    ``ltp_amp``). Padding carries weight 0, an exact ``+0.0``."""
+    arrived = hist == jnp.float32(cfg.ltp_amp)
+    return _ordered_sum(jnp.where(arrived, weight.astype(jnp.float32), 0.0))
+
+
+def delay_stdp_step(
+    cfg: DelaySTDPConfig,
+    state: DelaySTDPState,
+    weight: jax.Array,  # [F, Q] weight dtype
+    hist: jax.Array,  # [F, Q] f32, :func:`delay_history` at this tick
+    valid: jax.Array,  # [F, Q] bool — False on row padding
+    pre_spikes: jax.Array,  # [n_pre] bool, tick t
+    post_spikes: jax.Array,  # [n_post] bool, tick t
+    t: jax.Array,  # absolute tick
+) -> tuple[DelaySTDPState, jax.Array]:
+    """One tick of :class:`DelaySTDPConfig` after the neuron update.
+
+    ``sd <- (sd - LTD) + LTP`` with both terms elementwise per synapse,
+    stored once in the weight dtype; then the traces step (set on a spike,
+    else ``decay *``), and on the period's last tick the weight update."""
+    f32 = jnp.float32
+    arrived = (hist == f32(cfg.ltp_amp)) & valid
+    ltd_term = jnp.where(arrived, state.ltd[None, :], 0.0)
+    ltp_term = jnp.where(post_spikes[None, :] & valid, hist, 0.0)
+    sd = ((state.sd.astype(f32) - ltd_term) + ltp_term).astype(state.sd.dtype)
+    ltp_now = jnp.where(pre_spikes, f32(cfg.ltp_amp),
+                        f32(cfg.decay) * state.ltp_hist[0])
+    ltp_hist = jnp.concatenate([ltp_now[None], state.ltp_hist[:-1]])
+    ltd = jnp.where(post_spikes, f32(cfg.ltd_amp), f32(cfg.decay) * state.ltd)
+
+    def update(ws):
+        w, sd = ws
+        w2 = jnp.clip((w.astype(f32) + f32(cfg.bias)) + sd.astype(f32),
+                      cfg.w_min, cfg.w_max)
+        w2 = jnp.where(valid, w2, 0.0).astype(w.dtype)
+        return w2, (f32(cfg.sd_decay) * sd.astype(f32)).astype(sd.dtype)
+
+    every = cfg.update_every
+    weight, sd = jax.lax.cond(t % every == every - 1, update, lambda ws: ws,
+                              (weight, sd))
+    return DelaySTDPState(ltp_hist=ltp_hist, ltd=ltd, sd=sd), weight
 
 
 # -- homeostatic synaptic scaling (CARLsim setHomeostasis) ---------------------

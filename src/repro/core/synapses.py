@@ -28,6 +28,7 @@ __all__ = [
     "STPConfig",
     "STPState",
     "build_csr_direct",
+    "build_fanout",
     "build_fixed_fanin",
     "csr_layout",
     "csr_to_dense",
@@ -68,6 +69,10 @@ class ProjectionSpec:
     stp: STPConfig | None = None
     fanin: int = 0  # realized max in-degree (compile-time)
     n_syn: int = 0  # realized synapse count (compile-time)
+    # True when each synapse carries its own delay (``delay_ms`` is then the
+    # largest): the drive is read from the pre neurons' history at delivery
+    # instead of being pushed into the delay ring (``NetStatic.delay_csr``).
+    synapse_delays: bool = False
 
     @property
     def pre_slice(self) -> slice:
@@ -174,6 +179,9 @@ class CSRFanin(NamedTuple):
     idx: jax.Array  # [post, fanin] int16/int32
     weight: jax.Array  # [post, fanin] storage dtype
     valid: jax.Array | np.ndarray  # [post, fanin] bool — False on padding
+    # [post, fanin] host-side int delays (ms, >= 1; 1 on padding) when the
+    # projection sets a delay per synapse (:func:`build_fanout`), else None.
+    delay: np.ndarray | None = None
 
 
 def build_csr_direct(
@@ -240,6 +248,82 @@ def build_csr_direct(
         idx=jnp.asarray(idx.astype(idx_dtype)),
         weight=jnp.asarray(wq, storage_dtype),
         valid=valid,
+    )
+
+
+def build_fanout(
+    rng: np.random.Generator,
+    spec: ProjectionSpec,
+    fanout: int,
+    weight: float,
+    *,
+    delays: tuple[int, ...] | None = None,
+    storage_dtype=jnp.float32,
+) -> CSRFanin:
+    """Fixed fan-out connectivity (Izhikevich 2006, ``spnet``): each pre
+    neuron draws ``fanout`` distinct post neurons, never itself, straight
+    into CSR fan-in rows.
+
+    The draw, pre neuron by pre neuron in index order: one float64
+    ``rng.random(n_post)`` row of keys (drawn 512 rows at a time to bound
+    host memory; the stream is the same), the neuron's own column set to 2
+    where the post span holds it, and its synapse ``j`` goes to the
+    column of the ``j``-th smallest key. ``delays[j]`` (ms) is then the
+    delay of every pre neuron's synapse ``j``; ``None`` leaves the
+    projection's single delay in force and the result without a delay
+    table.
+
+    Fan-in rows list each post neuron's synapses in ascending pre index
+    (a pre neuron reaches a post neuron at most once), padded at the end
+    with index 0, weight 0 and delay 1 up to the next power of two of the
+    widest row — so the row width, hence every compiled shape, is the same
+    for nearly every seed.
+    """
+    n_pre, n_post = spec.pre_size, spec.post_size
+    if delays is not None and len(delays) != fanout:
+        raise ValueError(f"{spec.name}: {len(delays)} delays for fanout "
+                         f"{fanout}")
+    self_off = spec.pre_start - spec.post_start
+    overlap = (max(spec.pre_start, spec.post_start)
+               < min(spec.pre_start + n_pre, spec.post_start + n_post))
+    n_cand = n_post - int(overlap)
+    if not 0 < fanout <= n_cand:
+        raise ValueError(f"{spec.name}: fanout {fanout} outside "
+                         f"[1, {n_cand}] distinct targets")
+    targets = np.empty((n_pre, fanout), np.int64)
+    for p0 in range(0, n_pre, 512):
+        p1 = min(p0 + 512, n_pre)
+        keys = rng.random((p1 - p0, n_post))
+        own = np.arange(p0, p1) + self_off
+        inside = (own >= 0) & (own < n_post)
+        keys[np.flatnonzero(inside), own[inside]] = 2.0
+        sel = np.argpartition(keys, fanout - 1, axis=1)[:, :fanout]
+        order = np.argsort(np.take_along_axis(keys, sel, axis=1), axis=1)
+        targets[p0:p1] = np.take_along_axis(sel, order, axis=1)
+    pre = np.repeat(np.arange(n_pre), fanout)
+    post = targets.reshape(-1)
+    order = np.lexsort((pre, post))
+    pre, post = pre[order], post[order]
+    counts = np.bincount(post, minlength=n_post)
+    f = 1 << max(int(counts.max()) - 1, 0).bit_length()
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    col = np.arange(post.size) - starts[post]
+    idx = np.zeros((n_post, f), np.int64)
+    idx[post, col] = pre
+    valid = np.zeros((n_post, f), bool)
+    valid[post, col] = True
+    wq = np.where(valid, np.float32(weight), np.float32(0.0))
+    delay = None
+    if delays is not None:
+        slot = np.tile(np.arange(fanout), n_pre)[order]
+        delay = np.ones((n_post, f), np.int32)
+        delay[post, col] = np.asarray(delays, np.int32)[slot]
+    idx_dtype = np.int16 if n_pre <= np.iinfo(np.int16).max else np.int32
+    return CSRFanin(
+        idx=jnp.asarray(idx.astype(idx_dtype)),
+        weight=jnp.asarray(wq, storage_dtype),
+        valid=valid,
+        delay=delay,
     )
 
 

@@ -128,6 +128,9 @@ DECLARED: dict[str, tuple[str, str, tuple | None]] = {
         "(MemoryLedger.serve_rung_bytes)", None),
     "repro_bench_us_per_tick": (
         "gauge", "Best-of-N bench-cell microseconds per tick", None),
+    "repro_stdp_weight_updates_total": (
+        "counter", "Once-a-period DelaySTDP weight updates applied, counted "
+        "by the PlasticWeights monitor at each flush", None),
     "repro_fused_csr_chunks": (
         "gauge", "128-lane chunks the megakernel's CSR gather walks per "
         "tick, over each row tile's source window (walk=window) or the "

@@ -12,7 +12,8 @@ watch-off — and verdicts drain host-side at chunk/flush boundaries only.
 Specs
 -----
 - :class:`NonFinite` — NaN/Inf sentinel on the neuron membrane state every
-  tick and on plastic weights every ``weight_stride`` ticks. The fp16
+  tick and on plastic weights (and the weight-change accumulators ``sd`` of
+  DelaySTDP projections) every ``weight_stride`` ticks. The fp16
   poisoned-lane detector.
 - :class:`RateBand` — per-group mean firing rate must sit in
   ``[lo_hz, hi_hz]`` over the drained window (runaway / seizure detection).
@@ -205,14 +206,14 @@ def _l2(w: jax.Array) -> jax.Array:
 
 
 def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
-           v: jax.Array, weights: tuple) -> tuple:
+           v: jax.Array, weights: tuple, stdp: tuple = ()) -> tuple:
     """One watch tick: fold this tick's observables into the accumulators.
 
     Pure reads of the step output — never feeds back into the dynamics, so
     the simulation is bitwise identical with watches compiled in or out.
     ``i`` is the local step index (strided checks), ``spikes`` the [N] bool
     spike row, ``v`` the f32 membrane view, ``weights`` the post-update
-    weight storages.
+    weight storages, ``stdp`` the post-update plasticity states.
     """
     new: list = []
     for s, c in zip(static.watches, carry):
@@ -221,6 +222,7 @@ def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
             bad_v = bad_v + (~jnp.isfinite(v).all()).astype(jnp.int32)
             plastic = [w for w, cfg in zip(weights, static.stdp)
                        if cfg is not None]
+            plastic += [stdp[j].sd for j in static.delay_csr if stdp]
             if plastic:
                 def check(b, _ws=tuple(plastic)):
                     ok = jnp.bool_(True)

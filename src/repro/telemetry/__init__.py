@@ -11,6 +11,7 @@ from repro.telemetry.monitors import (
     DEFAULT_MONITORS,
     GroupRate,
     MonitorSpec,
+    PlasticWeights,
     SpikeCount,
     VoltageProbe,
     WeightNorm,
@@ -30,6 +31,7 @@ __all__ = [
     "DEFAULT_MONITORS",
     "GroupRate",
     "MonitorSpec",
+    "PlasticWeights",
     "SpikeCount",
     "VoltageProbe",
     "WeightNorm",

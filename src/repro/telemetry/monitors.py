@@ -37,6 +37,10 @@ Monitor kinds:
 * :class:`WeightNorm` — per-projection L2 weight norms snapshotted every
   ``stride`` ticks into a carry ring (``[⌈T/stride⌉, P]``); the cheap way
   to watch STDP drift without dumping weight matrices.
+* :class:`PlasticWeights` — the weight distribution of every
+  :class:`~repro.core.plasticity.DelaySTDPConfig` projection (mean, share
+  at ``w_min``, share at ``w_max``), read on each once-a-period weight
+  update, and the count of those updates since the last flush.
 
 The carry/ys layout is a tuple aligned with ``static.monitors``; all
 functions here are pure jnp so they vmap transparently under
@@ -57,6 +61,7 @@ __all__ = [
     "GroupRate",
     "VoltageProbe",
     "WeightNorm",
+    "PlasticWeights",
     "DEFAULT_MONITORS",
     "CUMULATIVE",
     "resolve",
@@ -102,7 +107,19 @@ class WeightNorm:
     name: str = "weight_norm"
 
 
-MonitorSpec = SpikeCount | GroupRate | VoltageProbe | WeightNorm
+@dataclasses.dataclass(frozen=True)
+class PlasticWeights:
+    """Per :class:`~repro.core.plasticity.DelaySTDPConfig` projection, in
+    ``static.delay_csr`` order: ``[mean w, share at w_min, share at w_max]``
+    over its synapses, read at every weight update (zeros before the
+    first), and the number of weight updates since the last flush. The
+    two shares track the bimodal distribution STDP drives the weights
+    to (Izhikevich 2006, Fig. 2)."""
+
+    name: str = "plastic_weights"
+
+
+MonitorSpec = SpikeCount | GroupRate | VoltageProbe | WeightNorm | PlasticWeights
 
 # What compile(monitors="default") attaches: exact counts (feeds the
 # paper's accuracy metric + bit-parity group rates) and the filtered rate.
@@ -129,7 +146,8 @@ def resolve(specs, *, n: int, n_projections: int,
     specs = tuple(specs)
     seen: set[str] = set()
     for s in specs:
-        if not isinstance(s, (SpikeCount, GroupRate, VoltageProbe, WeightNorm)):
+        if not isinstance(s, (SpikeCount, GroupRate, VoltageProbe, WeightNorm,
+                              PlasticWeights)):
             raise TypeError(f"not a monitor spec: {s!r}")
         if s.name in seen:
             raise ValueError(f"duplicate monitor name {s.name!r}")
@@ -178,6 +196,9 @@ def carry_struct(
         elif isinstance(s, WeightNorm):
             out.append(jax.ShapeDtypeStruct(
                 (n_snapshots(n_steps, s.stride), n_projections), jnp.float32))
+        elif isinstance(s, PlasticWeights):
+            out.append((jax.ShapeDtypeStruct((n_projections, 3), jnp.float32),
+                        jax.ShapeDtypeStruct((), jnp.int32)))
     return tuple(out)
 
 
@@ -197,6 +218,9 @@ def init_carry(static, n_steps: int) -> tuple:
             out.append(jnp.zeros(
                 (n_snapshots(n_steps, s.stride), len(static.projections)),
                 jnp.float32))
+        elif isinstance(s, PlasticWeights):
+            out.append((jnp.zeros((len(static.delay_csr), 3), jnp.float32),
+                        jnp.zeros((), jnp.int32)))
     return tuple(out)
 
 
@@ -205,7 +229,7 @@ def init_carry(static, n_steps: int) -> tuple:
 # host flush drains them. VoltageProbe emits per-tick ys and WeightNorm
 # keeps a per-run snapshot ring — both are per-chunk outputs, re-initialized
 # every call (their buffer shapes depend on the call's n_steps).
-CUMULATIVE = (SpikeCount, GroupRate)
+CUMULATIVE = (SpikeCount, GroupRate, PlasticWeights)
 
 
 def chunk_carry(static, carry: tuple | None, n_steps: int) -> tuple:
@@ -260,6 +284,17 @@ def flush_carry(static, carry: tuple) -> tuple[dict, tuple]:
                 c[g.start:g.start + g.size].mean() for g in static.groups
             ]))
             new.append(c)  # filter level persists — see docstring
+        elif isinstance(s, PlasticWeights):
+            # The weight readings are a level (kept); the update count a
+            # windowed sum (re-zeroed). One copy carries both.
+            stats, count = c
+            host = _read(jnp.concatenate(
+                [stats.reshape(-1), count.astype(jnp.float32)[None]]))
+            out[s.name] = host[:-1].reshape(stats.shape)
+            out["weight_updates"] = int(host[-1])
+            obs.inc("repro_stdp_weight_updates_total",
+                    float(out["weight_updates"]))
+            new.append((stats, jnp.zeros_like(count)))
         else:
             new.append(c)
     return out, tuple(new)
@@ -274,7 +309,8 @@ def _read(x: jax.Array) -> np.ndarray:
 
 
 def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
-           v: jax.Array, weights: tuple) -> tuple[tuple, tuple]:
+           v: jax.Array, weights: tuple,
+           t: jax.Array | None = None) -> tuple[tuple, tuple]:
     """One telemetry tick: fold this tick's spikes/voltages/weights into the
     accumulators. Returns ``(carry', ys)`` with ``ys`` aligned to
     ``static.monitors`` (``None`` for carry-only monitors).
@@ -286,8 +322,10 @@ def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
     (``benchmarks/bench_engine.py::monitor_overhead``).
 
     ``i`` is the *local* step index within the scan (0-based), used for
-    snapshot strides; spike/voltage values are read-only so the simulation
-    dynamics are untouched (raster-mode runs stay bit-identical).
+    snapshot strides; ``t`` the absolute tick, which places the weight
+    updates :class:`PlasticWeights` reads. Spike/voltage values are
+    read-only so the simulation dynamics are untouched (raster-mode runs
+    stay bit-identical).
     """
     new_carry, ys = [], []
     for s, c in zip(static.monitors, carry):
@@ -317,7 +355,35 @@ def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
             new_carry.append(jax.lax.cond(i % s.stride == 0, write,
                                           lambda b: b, c))
             ys.append(None)
+        elif isinstance(s, PlasticWeights):
+            new_carry.append(_plastic_weights(static, c, t, weights))
+            ys.append(None)
     return tuple(new_carry), tuple(ys)
+
+
+def _plastic_weights(static, carry, t, weights):
+    """Fold one tick into a :class:`PlasticWeights` slot: on a tick that
+    applied a weight update, re-read that projection's distribution and
+    count the update. Padding holds weight 0, so it is taken off the
+    count at ``w_min`` when that is 0."""
+    stats, count = carry
+    for r, j in enumerate(static.delay_csr):
+        cfg, spec = static.stdp[j], static.projections[j]
+        fired = t % cfg.update_every == cfg.update_every - 1
+
+        def read(st, w=weights[j], cfg=cfg, spec=spec, r=r):
+            w = w.astype(jnp.float32)
+            n_pad = w.size - spec.n_syn if cfg.w_min == 0 else 0
+            row = jnp.stack([
+                w.sum() / spec.n_syn,
+                ((w == cfg.w_min).sum() - n_pad) / spec.n_syn,
+                (w == cfg.w_max).sum() / spec.n_syn,
+            ]).astype(jnp.float32)
+            return st.at[r].set(row)
+
+        stats = jax.lax.cond(fired, read, lambda st: st, stats)
+        count = count + fired.astype(jnp.int32)
+    return stats, count
 
 
 def collect(static, carry: tuple, ys: tuple) -> dict:
@@ -336,6 +402,8 @@ def collect(static, carry: tuple, ys: tuple) -> dict:
             ])
         elif isinstance(s, VoltageProbe):
             out[s.name] = y
+        elif isinstance(s, PlasticWeights):
+            out[s.name], out["weight_updates"] = c
         else:
             out[s.name] = c
     return out
@@ -374,6 +442,9 @@ def summarize(static, telemetry: dict, n_steps: int) -> dict:
             out["group_rate_filtered_hz"] = {
                 g.name: float(r) for g, r in zip(static.groups, val)
             }
+        elif isinstance(spec, PlasticWeights):
+            out[spec.name] = val
+            out["weight_updates"] = int(np.asarray(telemetry["weight_updates"]))
         else:  # VoltageProbe / WeightNorm: pass the array through
             out[spec.name] = val
     return out

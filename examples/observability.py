@@ -12,8 +12,9 @@ What the operator of a `repro.serve` deployment actually sees — the
    ``dispatch`` (the jit call, which returns before the device is done);
    the first flush after it closes the ``chunk`` span once the chunk's
    outputs are ready, which feeds the ``repro_serve_chunk_latency_ms`` /
-   ``repro_serve_us_per_tick`` histograms. Every ``flush`` holds one
-   ``read`` per device-to-host copy (``repro_flush_host_reads_total``);
+   ``repro_serve_us_per_tick`` histograms. Every ``flush`` is one compiled
+   drain and one ``read``, its device-to-host copy
+   (``repro_flush_host_reads_total``);
    compilations are filed under the span that ran them.
 3. Dump the observability record: a JSONL trace, a Chrome trace you can
    open at https://ui.perfetto.dev, the Prometheus text snapshot, the

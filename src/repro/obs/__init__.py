@@ -42,8 +42,8 @@ trace):
   flush waits for them. ``repro_serve_chunk_latency_ms`` and
   ``repro_serve_us_per_tick`` read it. A chunk that no flush follows
   before the next step is not timed.
-* ``flush`` — a telemetry drain, enclosing one ``read`` per device-to-host
-  copy (``repro_flush_host_reads_total``).
+* ``flush`` — a telemetry drain: one compiled program, then one ``read``,
+  its single device-to-host copy (``repro_flush_host_reads_total``).
 * ``compile`` — an instant per executable a dispatch had to obtain,
   compiled or loaded from the persistent compilation cache (JAX's
   ``backend_compile_duration`` event), tagged with the innermost open span

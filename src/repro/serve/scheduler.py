@@ -547,15 +547,19 @@ class LaneScheduler:
     def flush(self, session_id: str) -> dict:
         """Drain one session's cumulative telemetry to the host: per-group
         spike counts since its last flush (lane accumulator re-zeroed) and
-        the current filtered group rates (filter level kept)."""
+        the current filtered group rates (filter level kept).
+
+        One compiled program over the whole (donated) fleet carry, the
+        lane a traced index, then one host copy
+        (``telemetry.monitors.flush_carry`` on a ``LaneCarry``); the other
+        lanes' slots come back bitwise untouched."""
         if not self._tel:
             raise ValueError("scheduler built with record='none'")
         lane = self.lane_of(session_id)
         with obs.span("flush", rung=self._obs_rung, session=session_id):
             self._chunks.close()
-            values, zeroed = tel.flush_carry(self.net.static,
-                                             _read_lane(self._tel, lane))
-            self._tel = _write_lane(self._tel, lane, zeroed)
+            values, self._tel = tel.flush_carry(
+                self.net.static, tel.LaneCarry(self._tel, lane))
             values["n_ticks"] = self._ticks_since_flush[lane]
             self._ticks_since_flush[lane] = 0
         obs.inc("repro_serve_flushes_total", rung=self._obs_rung)

@@ -87,7 +87,9 @@ class SessionMonitors:
         covered since the previous flush}``. Exact: the flushed spike
         counts over a chunk sequence sum to the uninterrupted run's totals
         bit-for-bit (counts re-zero on device; the rate-filter level
-        persists). O(N) work per flush regardless of elapsed ticks.
+        persists). O(N) work per flush regardless of elapsed ticks: one
+        compiled program over the (donated) carry and one host copy
+        (``telemetry.monitors.flush_carry``).
         """
         if self.carry is None:
             raise RuntimeError("flush() before any chunk has run")

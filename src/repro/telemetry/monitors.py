@@ -49,6 +49,7 @@ functions here are pure jnp so they vmap transparently under
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,7 @@ __all__ = [
     "init_carry",
     "chunk_carry",
     "flush_carry",
+    "LaneCarry",
     "update",
     "collect",
     "summarize",
@@ -247,13 +249,22 @@ def chunk_carry(static, carry: tuple | None, n_steps: int) -> tuple:
     )
 
 
-def flush_carry(static, carry: tuple) -> tuple[dict, tuple]:
+@dataclasses.dataclass(frozen=True)
+class LaneCarry:
+    """One lane of a fleet's batched telemetry carry (lane axis first), as
+    :func:`flush_carry` drains it for ``LaneScheduler.flush``."""
+
+    fleet: tuple
+    lane: int
+
+
+def flush_carry(static, carry: tuple | LaneCarry) -> tuple[dict, tuple]:
     """Drain the cumulative accumulators to the host; returns
     ``(host_values, carry')`` (per-chunk slots pass through untouched).
 
     ``host_values`` maps monitor name → numpy array of per-group values —
-    the same per-group reductions :func:`collect` runs post-scan. The two
-    cumulative kinds drain differently, by what they *are*:
+    the same per-group expressions :func:`collect` runs post-scan. The
+    cumulative kinds drain by what they *are*:
 
     * ``SpikeCount`` is a windowed sum: flushed counts are exact per-group
       totals **since the previous flush**, and the slot re-zeros on device
@@ -265,47 +276,103 @@ def flush_carry(static, carry: tuple) -> tuple[dict, tuple]:
       post-flush reading low by ~(1 − e^(−window/τ)) — readings would
       diverge from an uninterrupted run's, breaking the serving
       invariance).
+    * ``PlasticWeights``: the weight readings are a level (kept), the
+      update count a windowed sum (re-zeroed; also added to
+      ``repro_stdp_weight_updates_total``).
 
-    Cost is O(N) per flush, independent of how many ticks elapsed — the
-    periodic host sync of an unbounded serving session. Each monitor's
-    per-group values cross to the host in one copy (a ``read`` span,
-    counted by ``repro_flush_host_reads_total``).
+    The drain is ONE compiled program (keyed on ``static`` and the carry's
+    shapes, so every session of a topology shares it) that takes the
+    cumulative slots as donated buffers and returns the drained slots and
+    every monitor's per-group values packed into one int32 buffer (f32
+    values bit-cast, so counts stay exact integers and levels exact
+    floats); that buffer crosses to the host in ONE copy (a ``read`` span,
+    one ``repro_flush_host_reads_total``). Cost is O(N) per flush,
+    independent of how many ticks elapsed.
+
+    Given a :class:`LaneCarry`, the same program drains one lane of a
+    batched fleet carry — the lane a traced ``int32``, so one compile
+    serves every lane — and returns the whole fleet carry with only that
+    lane's windowed slots re-zeroed. The fleet carry is donated too.
     """
-    out: dict = {}
-    new = []
+    lane = None
+    if isinstance(carry, LaneCarry):
+        carry, lane = carry.fleet, np.int32(carry.lane)
+    cumulative = tuple(c if isinstance(s, CUMULATIVE) else ()
+                       for s, c in zip(static.monitors, carry))
+    packed, drained = _drain(static, cumulative, lane)
+    out = _unpack(static, _read(packed))
+    if "weight_updates" in out:
+        obs.inc("repro_stdp_weight_updates_total",
+                float(out["weight_updates"]))
+    return out, tuple(d if isinstance(s, CUMULATIVE) else c
+                      for s, c, d in zip(static.monitors, carry, drained))
+
+
+@partial(jax.jit, static_argnums=0, donate_argnums=1)
+def _drain(static, carry: tuple, lane):
+    """The compiled drain of :func:`flush_carry`: ``(packed int32 [K],
+    carry')``. ``lane`` is None for a session's carry, else the traced
+    lane of a batched one."""
+    def take(x):
+        return x if lane is None else x[lane]
+
+    def reset(x):
+        return jnp.zeros_like(x) if lane is None else x.at[lane].set(0)
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    parts, new = [], []
     for s, c in zip(static.monitors, carry):
         if isinstance(s, SpikeCount):
-            out[s.name] = _read(jnp.stack([
-                c[g.start:g.start + g.size].sum() for g in static.groups
-            ]))
-            new.append(jnp.zeros_like(c))
+            parts.append(_per_group(static, take(c), "sum"))
+            c = reset(c)
         elif isinstance(s, GroupRate):
-            out[s.name] = _read(jnp.stack([
-                c[g.start:g.start + g.size].mean() for g in static.groups
-            ]))
-            new.append(c)  # filter level persists — see docstring
+            parts.append(bits(_per_group(static, take(c), "mean")))
         elif isinstance(s, PlasticWeights):
-            # The weight readings are a level (kept); the update count a
-            # windowed sum (re-zeroed). One copy carries both.
             stats, count = c
-            host = _read(jnp.concatenate(
-                [stats.reshape(-1), count.astype(jnp.float32)[None]]))
-            out[s.name] = host[:-1].reshape(stats.shape)
-            out["weight_updates"] = int(host[-1])
-            obs.inc("repro_stdp_weight_updates_total",
-                    float(out["weight_updates"]))
-            new.append((stats, jnp.zeros_like(count)))
-        else:
-            new.append(c)
-    return out, tuple(new)
+            parts += [bits(take(stats).reshape(-1)), take(count)[None]]
+            c = (stats, reset(count))
+        new.append(c)
+    packed = (jnp.concatenate(parts) if parts
+              else jnp.zeros((0,), jnp.int32))
+    return packed, tuple(new)
+
+
+def _unpack(static, host: np.ndarray) -> dict:
+    """Host side of :func:`_drain`: slice the packed int32 buffer back into
+    per-monitor arrays (f32 slots viewed, not converted)."""
+    out: dict = {}
+    n_groups, at = len(static.groups), 0
+    for s in static.monitors:
+        if isinstance(s, SpikeCount):
+            out[s.name] = host[at:at + n_groups]
+            at += n_groups
+        elif isinstance(s, GroupRate):
+            out[s.name] = host[at:at + n_groups].view(np.float32)
+            at += n_groups
+        elif isinstance(s, PlasticWeights):
+            size = 3 * len(static.delay_csr)
+            out[s.name] = host[at:at + size].view(np.float32).reshape(-1, 3)
+            out["weight_updates"] = int(host[at + size])
+            at += size + 1
+    return out
 
 
 def _read(x: jax.Array) -> np.ndarray:
-    """One device-to-host copy of a flush."""
+    """The one device-to-host copy of a flush."""
     with obs.span("read"):
         host = np.asarray(x)
     obs.inc("repro_flush_host_reads_total")
     return host
+
+
+def _per_group(static, c: jax.Array, reduce: str) -> jax.Array:
+    """Per-group ``sum`` or ``mean`` of a per-neuron slot, ``[G]``: the one
+    expression :func:`collect` and :func:`flush_carry` share, so a flushed
+    value is what an uninterrupted run's ``collect`` gives."""
+    return jnp.stack([getattr(c[g.start:g.start + g.size], reduce)()
+                      for g in static.groups])
 
 
 def update(static, carry: tuple, i: jax.Array, spikes: jax.Array,
@@ -393,13 +460,9 @@ def collect(static, carry: tuple, ys: tuple) -> dict:
     out = {}
     for s, c, y in zip(static.monitors, carry, ys):
         if isinstance(s, SpikeCount):
-            out[s.name] = jnp.stack([
-                c[g.start:g.start + g.size].sum() for g in static.groups
-            ])
+            out[s.name] = _per_group(static, c, "sum")
         elif isinstance(s, GroupRate):
-            out[s.name] = jnp.stack([
-                c[g.start:g.start + g.size].mean() for g in static.groups
-            ])
+            out[s.name] = _per_group(static, c, "mean")
         elif isinstance(s, VoltageProbe):
             out[s.name] = y
         elif isinstance(s, PlasticWeights):

@@ -454,18 +454,29 @@ class TestServeInstrumentation:
             scope="session", rung="solo") == 1
 
     def test_flush_counts_two_host_reads_with_default_monitors(self):
+        """Both default monitors (SpikeCount, GroupRate) now cross in ONE
+        host copy per flush: one ``read`` inside each ``flush`` span, one
+        ``repro_flush_host_reads_total``, and no compile after the first."""
         sess = Session.create(_mini(), seed=4)
-        for _ in range(3):
+        for i in range(3):
             sess.run(20)
             sess.flush()
+            if i == 0:
+                warm = obs.registry().counter("repro_compiles_total").value(
+                    site="flush")
         reads = obs.registry().counter("repro_flush_host_reads_total")
-        assert reads.value() == 6.0
+        assert reads.value() == 3.0
+        assert obs.registry().counter("repro_compiles_total").value(
+            site="flush") == warm
         events = obs.tracer().snapshot()
         flushes = [e for e in events if e.name == "flush"]
-        inside = [e for e in events if e.name == "read"
-                  and any(f.ts_us <= e.ts_us and e.ts_us + e.dur_us
-                          <= f.ts_us + f.dur_us for f in flushes)]
-        assert len(flushes) == 3 and len(inside) == 6
+        for f in flushes:
+            inside = [e for e in events if e.name == "read"
+                      and f.ts_us <= e.ts_us
+                      and e.ts_us + e.dur_us <= f.ts_us + f.dur_us]
+            assert len(inside) == 1
+        assert len(flushes) == 3
+        assert sum(e.name == "read" for e in events) == 3
 
 # ---------------------------------------------------------------------------
 # health snapshots vs the paper's budgets

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.configs.izhikevich2006 import IZH2006, build_izhikevich2006
 from repro.configs.synfire4 import SYNFIRE4_MINI, CHAIN_STDP, build_synfire
 from repro.core import Engine, NetworkBuilder, STDPConfig, izh4
 from repro.core.plasticity import HomeostasisConfig
@@ -26,6 +28,7 @@ from repro.serve import (
     restore_session,
     save_session,
 )
+from repro.telemetry import GroupRate, PlasticWeights, SpikeCount
 
 MODES = [("packed", "xla"), ("sparse", "xla"), ("auto", "xla"),
          ("packed", "pallas"), ("sparse", "pallas"), ("auto", "pallas")]
@@ -208,6 +211,113 @@ class TestSessionMonitors:
         assert "spikes" not in out
         assert "tel_carry" not in out  # absorbed into the session
         assert "telemetry" in out
+
+
+def _plastic_mini():
+    """100 neurons with all three cumulative monitors (SpikeCount,
+    GroupRate, PlasticWeights): Izhikevich 2006's rule, one weight update
+    per 1,000 ticks."""
+    cfg = dataclasses.replace(IZH2006, name="izh_mini", n_exc=80, n_inh=20,
+                              fanout=20, max_delay=5)
+    return build_izhikevich2006(cfg, policy="fp16", seed=3)
+
+
+def _eager_drain(static, carry):
+    """The per-group reductions as the eager flush ran them, op by op, on
+    a host copy of the carry."""
+    out = {}
+    for s, c in zip(static.monitors, jax.tree.map(jnp.asarray, carry)):
+        if isinstance(s, SpikeCount):
+            out[s.name] = np.asarray(jnp.stack([
+                c[g.start:g.start + g.size].sum() for g in static.groups]))
+        elif isinstance(s, GroupRate):
+            out[s.name] = np.asarray(jnp.stack([
+                c[g.start:g.start + g.size].mean() for g in static.groups]))
+        elif isinstance(s, PlasticWeights):
+            out[s.name] = np.asarray(c[0])
+            out["weight_updates"] = int(c[1])
+    return out
+
+
+def _flush_compiles():
+    return obs.registry().counter("repro_compiles_total").value(site="flush")
+
+
+class TestCompiledFlush:
+    """The flush is one compiled program and one host copy, bitwise the
+    eager per-group reductions it replaced."""
+
+    @pytest.mark.parametrize("which", ["synfire", "plastic"])
+    def test_flush_is_bitwise_the_eager_reduction(self, which):
+        net = (_plastic_mini() if which == "plastic"
+               else build_synfire(SYNFIRE4_MINI, policy="fp16"))
+        static = net.static
+        sess = Session.create(net, seed=7)
+        totals = []
+        for n_ticks in (1000, 500):
+            sess.run(n_ticks)
+            before = jax.tree.map(np.asarray, sess.monitors.carry)
+            want = _eager_drain(static, before)
+            got = sess.flush()
+            assert got.keys() == want.keys() | {"n_ticks"}
+            for k, v in want.items():
+                assert np.asarray(got[k]).dtype == np.asarray(v).dtype, k
+                assert np.asarray(got[k]).tobytes() == \
+                    np.asarray(v).tobytes(), k
+            after = jax.tree.map(np.asarray, sess.monitors.carry)
+            for s, b, a in zip(static.monitors, before, after):
+                if isinstance(s, SpikeCount):
+                    assert not a.any()
+                elif isinstance(s, GroupRate):
+                    assert a.tobytes() == b.tobytes()  # the level is kept
+                elif isinstance(s, PlasticWeights):
+                    assert a[0].tobytes() == b[0].tobytes() and a[1] == 0
+            totals.append(got["spike_count"].sum())
+        assert totals[0] > 0
+
+    def test_flush_sums_equal_one_uninterrupted_run(self):
+        net = _plastic_mini()
+        eng = Engine(net)
+        sess = Session.create(eng, seed=9)
+        flushes = []
+        for n_ticks in (400, 600, 1000):
+            sess.run(n_ticks)
+            flushes.append(sess.flush())
+        _, whole = eng.run(2000, gen_base=sess.gen_key, record="monitors")
+        want = jax.tree.map(np.asarray, whole["telemetry"])
+        assert np.array_equal(sum(f["spike_count"] for f in flushes),
+                              want["spike_count"])
+        assert sum(f["weight_updates"] for f in flushes) == \
+            want["weight_updates"] == 2
+        # levels: the last flush reads what the whole run's collect gives
+        for k in ("group_rate", "plastic_weights"):
+            assert flushes[-1][k].tobytes() == want[k].tobytes(), k
+
+    def test_one_compile_serves_every_session_of_an_engine(self):
+        """After one warm flush, more flushes (and the first flush of new
+        sessions on the same Engine) compile nothing; the first flush on a
+        cold cache compiles exactly one program."""
+        eng = Engine(_plastic_mini())
+        sess = Session.create(eng, seed=1)
+        sess.run(100)
+        jax.clear_caches()
+        try:
+            c0 = _flush_compiles()
+            sess.flush()
+            assert _flush_compiles() == c0 + 1
+            reads = obs.registry().counter("repro_flush_host_reads_total")
+            r0 = reads.value()
+            for seed in (1, 2, 3):
+                other = Session.create(eng, seed=seed)
+                for _ in range(2):
+                    other.run(100)
+                    other.flush()
+            sess.run(100)
+            sess.flush()
+            assert _flush_compiles() == c0 + 1
+            assert reads.value() == r0 + 7
+        finally:
+            jax.clear_caches()
 
 
 class TestLaneScheduler:

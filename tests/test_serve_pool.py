@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.synfire4 import SYNFIRE4_MINI, CHAIN_STDP, build_synfire
 from repro.core.plasticity import HomeostasisConfig
 from repro.serve import (
@@ -28,6 +29,9 @@ from repro.serve import (
     restore_lane,
     save_lane,
 )
+from repro.telemetry import PlasticWeights, SpikeCount
+
+from test_serve import _eager_drain, _plastic_mini
 
 MODES = [("packed", "xla"), ("sparse", "xla"), ("auto", "xla"),
          ("packed", "fused"), ("sparse", "fused"), ("auto", "fused")]
@@ -245,6 +249,70 @@ class TestRecycledLane:
         _assert_flush_eq(flush_r, flush_v)
         _assert_state_eq(sched.evict("fresh").state,
                          virgin.evict("fresh").state, "recycled lane state")
+
+
+class TestLaneFlush:
+    """``LaneScheduler.flush`` is one compiled program over the donated
+    fleet carry, the lane a traced index, and one host copy: it drains
+    that lane alone."""
+
+    @pytest.mark.parametrize("plastic", [False, True])
+    def test_lane_flush_touches_only_its_lane(self, plastic):
+        net = (_plastic_mini() if plastic
+               else _mini("fp16", "packed", "xla"))
+        monitors = net.static.monitors
+        sched = LaneScheduler(net, 3)
+        for sid in ("a", "b", "c"):
+            sched.admit(sid)
+        sched.step(1000 if plastic else 80)
+        lane = sched.lane_of("b")
+        before = jax.tree.map(np.asarray, sched._tel)
+        got = sched.flush("b")
+        after = jax.tree.map(np.asarray, sched._tel)
+
+        want = _eager_drain(net.static,
+                            jax.tree.map(lambda x: x[lane], before))
+        for k, v in want.items():
+            assert np.asarray(got[k]).tobytes() == np.asarray(v).tobytes(), k
+        assert got["spike_count"].sum() > 0
+        assert got.get("weight_updates", 1) == 1
+        others = [i for i in range(3) if i != lane]
+        for s, b, a in zip(monitors, before, after):
+            # leaves that are windowed sums: SpikeCount, the update count
+            zeroed = {SpikeCount: (0,), PlasticWeights: (1,)}.get(type(s), ())
+            leaves = zip(jax.tree.leaves(b), jax.tree.leaves(a))
+            for i, (x, y) in enumerate(leaves):
+                assert x[others].tobytes() == y[others].tobytes()
+                if i in zeroed:
+                    assert not y[lane].any()
+                else:  # levels are kept
+                    assert x[lane].tobytes() == y[lane].tobytes()
+
+    def test_one_program_and_one_copy_for_every_lane(self):
+        """After the first lane flush, flushing the other lanes (and this
+        lane again, and ``flush_all``) compiles nothing; every flush makes
+        one host copy."""
+        net = _mini("fp16", "packed", "xla")
+        sched = LaneScheduler(net, 4)
+        for i in range(4):
+            sched.admit(f"t{i}")
+        sched.step(40)
+        reg = obs.registry()
+        reads = reg.counter("repro_flush_host_reads_total")
+        compiles = reg.counter("repro_compiles_total")
+        jax.clear_caches()
+        try:
+            c0, r0 = compiles.value(site="flush"), reads.value()
+            sched.flush("t0")
+            assert compiles.value(site="flush") == c0 + 1
+            for sid in ("t1", "t2", "t3", "t0"):
+                sched.flush(sid)
+            sched.step(40)
+            assert len(sched.flush_all()) == 4
+            assert compiles.value(site="flush") == c0 + 1
+            assert reads.value() == r0 + 9
+        finally:
+            jax.clear_caches()
 
 
 class TestLedgerRungBytes:
